@@ -22,14 +22,14 @@ identical costs and search results never depend on the backend:
   products fall back to the scalar path element-wise.
 * ``jax``     — same struct-of-arrays batching as ``vector``, but the
   capacity/streaming/weight-sharing arithmetic runs as a jit-compiled jnp
-  kernel (optionally a Pallas kernel for the streaming-block sweep) on
-  whatever device jax targets (:mod:`repro.kernels.finish_batch`).  Wins on
-  accelerator-resident generation evaluation — a whole GA generation's
-  distinct queries become one device call.  The same element-wise guards as
-  ``vector`` route out-of-range inputs to the scalar path, so it is
-  bit-identical to ``serial`` too.  jax is an optional dependency: when it
-  is not importable, :func:`make_executor` reports *why* and every other
-  backend keeps working.
+  kernel on whatever device jax targets
+  (:mod:`repro.kernels.finish_batch`).  Wins on accelerator-resident
+  generation evaluation — a whole GA generation's distinct queries become
+  one device call.  The same element-wise guards as ``vector`` route
+  out-of-range inputs to the scalar path, so it is bit-identical to
+  ``serial`` too.  jax is an optional dependency: when it is not installed,
+  :func:`make_executor` reports *why* and every other backend keeps
+  working; any other failure to load the kernel raises.
 
 Pick a backend by name via :func:`make_executor` — the seam the API layer's
 ``eval_backend``/``eval_jobs`` options thread through;
@@ -39,7 +39,6 @@ anything (the CLI's pre-flight check).
 
 from __future__ import annotations
 
-import os
 from concurrent.futures import ProcessPoolExecutor
 from dataclasses import fields as dataclass_fields
 from typing import List, Optional, Sequence, Tuple
@@ -78,10 +77,12 @@ def needs_scalar_fallback(st: SubgraphStructure,
     The ``share * weight_total`` clause bounds the NoC product: with it (and
     the footprint bound on the block count), ``(share - 1) * ema_w`` stays
     below ``2**62`` even for a streamed sweep, so int64 cannot overflow.
+    It also keeps ``share`` itself below ``2**31`` (a zero weight total
+    counts as one), so the device kernel can divide in int32.
     """
     return (st.sched_error is not None
             or max(st.footprint, st.weight_total) >= _PROD_SAFE
-            or acc.weight_share_cores * st.weight_total >= _PROD_SAFE
+            or acc.weight_share_cores * max(st.weight_total, 1) >= _PROD_SAFE
             or max(acc.glb_bytes, acc.wbuf_bytes) >= _FLOAT_EXACT)
 
 
@@ -397,46 +398,46 @@ def jax_status() -> Tuple[bool, str]:
     """``(available, detail)`` for the ``jax`` backend.
 
     ``detail`` is ``""`` when the batched kernel module imports cleanly and
-    the import failure (e.g. ``ModuleNotFoundError: No module named 'jax'``)
-    otherwise.  The probe runs once per process; jax is an optional
-    dependency, so failure here is a normal, reportable state — never an
-    error by itself.
+    the import failure (``ModuleNotFoundError: No module named 'jax'``)
+    when jax is not installed.  jax is an optional dependency, so its
+    absence is a normal, reportable state.  Any other failure to import the
+    kernel module (an installed jax that lacks an API the kernel uses, say)
+    is a bug and raises.  The probe runs once per process.
     """
     global _JAX_STATUS
     if _JAX_STATUS is None:
         try:
+            import jax  # noqa: F401
+        except ModuleNotFoundError as err:
+            if err.name != "jax":
+                raise
+            _JAX_STATUS = (False, f"{type(err).__name__}: {err}")
+        else:
             from repro.kernels import finish_batch  # noqa: F401
             _JAX_STATUS = (True, "")
-        except Exception as err:  # ImportError or anything the import raised
-            _JAX_STATUS = (False, f"{type(err).__name__}: {err}")
     return _JAX_STATUS
 
 
 class JaxExecutor(_BatchedFinishExecutor):
-    """jit-compiled jnp/Pallas ``finish_cost`` over a whole generation.
+    """jit-compiled jnp ``finish_cost`` over a whole generation.
 
     The same struct-of-arrays batching as ``vector``, evaluated on-device
     through :func:`repro.kernels.finish_batch.finish_cost_batch` (int64
-    arithmetic under ``jax.experimental.enable_x64``, batches padded to
-    powers of two so GA generations of drifting size reuse compiled
-    kernels).  ``pallas=True`` routes the hot streaming-block sweep through
-    the Pallas kernel variant (interpret mode off-TPU); default comes from
-    ``$REPRO_JAX_PALLAS``.  Both variants are bit-identical to ``serial``.
+    arithmetic under ``jax.enable_x64``, batches padded to powers of two so
+    GA generations of drifting size reuse compiled kernels).  Bit-identical
+    to ``serial``.  Each batch adds to the ``engine.device_calls`` and
+    ``engine.device_lanes`` counters.
     """
 
     name = "jax"
 
-    def __init__(self, pallas: Optional[bool] = None) -> None:
-        if pallas is None:
-            pallas = os.environ.get("REPRO_JAX_PALLAS", "0") == "1"
-        self.pallas = bool(pallas)
-
     def _finish_arrays(self, fp, w_total, single, glb, wbuf, shared, share):
         from repro.kernels import finish_batch
 
+        obs.add("engine.device_calls")
+        obs.add("engine.device_lanes", len(fp))
         return finish_batch.finish_cost_batch(
-            fp, w_total, single, glb, wbuf, shared, share,
-            use_pallas=self.pallas)
+            fp, w_total, single, glb, wbuf, shared, share)
 
 
 BACKENDS = ("serial", "process", "vector", "jax")

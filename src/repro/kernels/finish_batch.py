@@ -1,4 +1,4 @@
-"""Batched ``finish_cost`` arithmetic as jit-compiled jnp / Pallas kernels.
+"""Batched ``finish_cost`` arithmetic as one jit-compiled jnp kernel.
 
 The accelerator-resident half of the ``jax`` executor backend
 (:class:`repro.core.engine.JaxExecutor`): a whole GA generation's distinct
@@ -10,9 +10,9 @@ Bitwise parity with the scalar kernel is the contract (the engine's guards
 keep every lane below ``2**53`` / int64-product-safe, see
 :func:`repro.core.engine.needs_scalar_fallback`), which pins the numerics:
 
-* all integer work is int64 under ``jax.experimental.enable_x64`` (the
-  context manager keeps x64 scoped to these calls — the rest of the repo's
-  jax code stays in its default 32-bit world);
+* all integer work is int64 under ``jax.enable_x64(True)`` (the context
+  manager keeps x64 scoped to these calls — the rest of the repo's jax code
+  stays in its default 32-bit world);
 * the streaming block count mirrors ``_stream_single_layer`` exactly:
   ``ceil`` of a float64 true division, whose operands are exact below
   ``2**53`` and whose IEEE result is therefore identical to the scalar
@@ -23,42 +23,66 @@ size (cache warmth changes the miss count every round) reuse a handful of
 compiled kernels instead of recompiling per shape; the arithmetic is
 element-wise, so padding lanes can never perturb real lanes.
 
-Two interchangeable variants, both validated by the differential-parity
-suite (``tests/test_backend_parity.py``):
-
-* :func:`_finish_jnp` — the default: the whole arithmetic as one jitted
-  jnp expression.
-* :func:`_finish_pallas` — the hot streaming-block sweep
-  (``n_blocks`` / ``ema_w`` / capped footprint) as a Pallas kernel in the
-  idiom of the other kernels in this package (interpret mode off-TPU),
-  with the cheap mask algebra staying in jnp.  Selected by
-  ``JaxExecutor(pallas=True)`` or ``$REPRO_JAX_PALLAS=1``.
+On an accelerator, compiled kernels go to JAX's persistent compilation
+cache: the directory ``$JAX_COMPILATION_CACHE_DIR`` names when it is set,
+else ``.jax_cache/`` at the root of the checkout (see
+:func:`compile_cache_dir`).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+import os
+from pathlib import Path
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
-from jax.experimental import pallas as pl
 
-# Pallas grid tile for the streaming-block sweep; a power of two so the
-# pow2-padded batch is always an exact number of tiles
-_STREAM_BLOCK = 256
+# fixed in-checkout cache path: the directory is part of the cache key, so
+# it must not move between runs
+_CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
-def _finish_masks(fp, wr, w_total, single, glb, wbuf, shared,
-                  n_blocks):
-    """The mask algebra shared by both variants (pure jnp, element-wise).
+@functools.cache
+def compile_cache_dir() -> Optional[str]:
+    """Place JAX's persistent compilation cache; return its directory.
+
+    Runs once, on the first batch (never at import).  When
+    ``$JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and no other
+    directory is set here.  These kernels compile in milliseconds, below
+    JAX's default one-second floor for writing an entry, so the floor is
+    dropped to make the entries land.  On the CPU backend nothing is placed
+    (``None``): XLA:CPU compiles the kernel in milliseconds and logs a
+    machine-feature warning for every entry it loads back.
+    """
+    if jax.default_backend() == "cpu":
+        return None
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(_CHECKOUT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path
+
+
+@jax.jit
+def _finish_jnp(fp, w_total, single, glb, wbuf, shared, share):
+    """Whole-batch ``finish_cost`` arithmetic as one jitted jnp expression.
 
     Mirrors ``finish_cost``'s branch structure: buffer overflow splits into
     infeasible (multi-node) vs streaming (single-node); separate-buffer
     weight overflow only ever invalidates multi-node subgraphs.
     """
+    # the guards keep 0 <= w_total < 2**31 and 1 <= share < 2**31, so the
+    # quotient is exact in int32; XLA:TPU emulates 64-bit integer division,
+    # and that emulation took most of the kernel's compile time
+    wr = (w_total.astype(jnp.int32)
+          // share.astype(jnp.int32)).astype(jnp.int64)
+    # mirrors _stream_single_layer: math.ceil of a float64 true division
+    n_blocks = jnp.maximum(
+        jnp.ceil(fp / jnp.maximum(glb, 1)).astype(jnp.int64), 1)
     wbuf_cap = jnp.where(shared, glb, wbuf)
     overflow = jnp.where(shared, fp + wr > glb, fp > glb)
     infeasible_buf = overflow & ~single
@@ -67,74 +91,13 @@ def _finish_masks(fp, wr, w_total, single, glb, wbuf, shared,
     fp_out = jnp.where(stream, jnp.minimum(fp, glb), fp)
     w_overflow = ~shared & ~single & ~infeasible_buf & (wr > wbuf_cap)
     feasible = ~(infeasible_buf | w_overflow)
-    return ema_w, fp_out, infeasible_buf, w_overflow, stream, feasible
-
-
-def _noc_bytes(share, ema_w):
-    """§5.4.2 NoC charge, mirroring ``finish_cost``: every DRAM-loaded
-    weight byte crosses the fabric to the ``share - 1`` peer cores.  The
-    engine's guards bound ``share * w_total`` below ``2**31``, so the
-    product stays int64-safe even for a streamed ``ema_w``."""
-    return (share - 1) * ema_w
-
-
-@jax.jit
-def _finish_jnp(fp, w_total, single, glb, wbuf, shared, share):
-    """Whole-batch ``finish_cost`` arithmetic as one jitted jnp expression."""
-    wr = w_total // share
-    # mirrors _stream_single_layer: math.ceil of a float64 true division
-    n_blocks = jnp.maximum(
-        jnp.ceil(fp / jnp.maximum(glb, 1)).astype(jnp.int64), 1)
-    (ema_w, fp_out, infeasible_buf, w_overflow, stream,
-     feasible) = _finish_masks(fp, wr, w_total, single, glb, wbuf, shared,
-                               n_blocks)
-    return (wr, n_blocks, ema_w, fp_out, _noc_bytes(share, ema_w),
-            infeasible_buf, w_overflow, stream, feasible)
-
-
-def _stream_blocks_kernel(fp_ref, glb_ref, wr_ref,
-                          nb_ref, emaw_ref, fpcap_ref):
-    """Pallas kernel: one tile of the single-layer streaming-block sweep.
-
-    Computes, per lane: the row-block count (``ceil`` of the float64 true
-    division, exactly as ``_stream_single_layer``), the re-streamed weight
-    bytes ``wr * n_blocks``, and the buffer-capped footprint.  Whether a
-    lane actually streams is decided by the jnp mask algebra outside — the
-    kernel is pure arithmetic, so every lane computes unconditionally.
-    """
-    fp = fp_ref[...]
-    glb = glb_ref[...]
-    nb = jnp.maximum(jnp.ceil(fp / jnp.maximum(glb, 1)).astype(jnp.int64), 1)
-    nb_ref[...] = nb
-    emaw_ref[...] = wr_ref[...] * nb
-    fpcap_ref[...] = jnp.minimum(fp, glb)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _finish_pallas(fp, w_total, single, glb, wbuf, shared, share,
-                   interpret=True):
-    """Variant routing the streaming-block sweep through the Pallas kernel."""
-    n = fp.shape[0]
-    block = min(_STREAM_BLOCK, n)  # both powers of two => exact tiling
-    spec = pl.BlockSpec((block,), lambda i: (i,))
-    wr = w_total // share
-    nb, emaw_stream, fp_cap = pl.pallas_call(
-        _stream_blocks_kernel,
-        grid=(n // block,),
-        in_specs=[spec, spec, spec],
-        out_specs=(spec, spec, spec),
-        out_shape=tuple(jax.ShapeDtypeStruct((n,), jnp.int64)
-                        for _ in range(3)),
-        interpret=interpret,
-    )(fp, glb, wr)
-    (ema_w, fp_out, infeasible_buf, w_overflow, stream,
-     feasible) = _finish_masks(fp, wr, w_total, single, glb, wbuf, shared,
-                               nb)
-    # the mask algebra re-selects from the kernel's unconditional results
-    ema_w = jnp.where(stream, emaw_stream, ema_w)
-    fp_out = jnp.where(stream, fp_cap, fp_out)
-    return (wr, nb, ema_w, fp_out, _noc_bytes(share, ema_w),
-            infeasible_buf, w_overflow, stream, feasible)
+    # §5.4.2 NoC charge, mirroring finish_cost: every DRAM-loaded weight
+    # byte crosses the fabric to the share - 1 peer cores; the engine's
+    # guards bound share * w_total below 2**31, so the product stays
+    # int64-safe even for a streamed ema_w
+    noc = (share - 1) * ema_w
+    return (wr, n_blocks, ema_w, fp_out, noc, infeasible_buf, w_overflow,
+            stream, feasible)
 
 
 def _pad_pow2(arr: np.ndarray, fill) -> np.ndarray:
@@ -149,8 +112,8 @@ def _pad_pow2(arr: np.ndarray, fill) -> np.ndarray:
     return out
 
 
-def finish_cost_batch(fp, w_total, single, glb, wbuf, shared, share,
-                      use_pallas: bool = False) -> Tuple[np.ndarray, ...]:
+def finish_cost_batch(fp, w_total, single, glb, wbuf, shared,
+                      share) -> Tuple[np.ndarray, ...]:
     """Evaluate a batch of ``finish_cost`` queries on the jax device.
 
     Inputs are index-aligned equal-length arrays (int64 values, bool
@@ -164,6 +127,7 @@ def finish_cost_batch(fp, w_total, single, glb, wbuf, shared, share,
         empty_i = np.zeros(0, dtype=np.int64)
         empty_b = np.zeros(0, dtype=bool)
         return (empty_i,) * 5 + (empty_b,) * 4
+    compile_cache_dir()
     # pad to the next power of two: neutral lanes (glb/share=1 avoids any
     # divide-by-zero path) that the element-wise arithmetic cannot couple
     # into real lanes
@@ -176,12 +140,6 @@ def finish_cost_batch(fp, w_total, single, glb, wbuf, shared, share,
         _pad_pow2(np.asarray(shared, dtype=bool), False),
         _pad_pow2(np.asarray(share, dtype=np.int64), 1),
     )
-    with enable_x64():
-        jargs = tuple(jnp.asarray(a) for a in args)
-        if use_pallas:
-            # interpret everywhere but real TPUs, like the other kernels
-            interpret = jax.default_backend() != "tpu"
-            outs = _finish_pallas(*jargs, interpret=interpret)
-        else:
-            outs = _finish_jnp(*jargs)
+    with jax.enable_x64(True):
+        outs = _finish_jnp(*(jnp.asarray(a) for a in args))
         return tuple(np.asarray(o)[:n] for o in outs)

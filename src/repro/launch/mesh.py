@@ -17,10 +17,21 @@ from repro.models.config import ModelConfig
 from repro.parallel.sharding import DEFAULT_RULES, LogicalRules
 
 
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    The sharding rules place arrays through ``with_sharding_constraint`` and
+    leave propagation to the compiler, which JAX allows only on ``Auto``
+    axes; ``jax.make_mesh`` itself defaults to ``Explicit`` ones.
+    """
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 MODEL_AXIS = 16  # TP/EP degree on the production meshes

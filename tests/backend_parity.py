@@ -156,15 +156,9 @@ def assert_costs_equal(got, want, context=""):
         f"SubgraphCost mismatch {context}: " + "; ".join(diffs))
 
 
-def assert_backend_parity(g, queries, backend, jobs=1, **executor_kw):
+def assert_backend_parity(g, queries, backend, jobs=1):
     """One backend's batch answers equal the scalar serial reference."""
-    if executor_kw:
-        from repro.core.engine import JaxExecutor
-
-        assert backend == "jax", "executor kwargs are jax-only"
-        ex = JaxExecutor(**executor_kw)
-    else:
-        ex = make_executor(backend, jobs)
+    ex = make_executor(backend, jobs)
     reference = CostKernel(g)
     try:
         got = ex.evaluate(CostKernel(g), queries)
@@ -174,7 +168,7 @@ def assert_backend_parity(g, queries, backend, jobs=1, **executor_kw):
     for (nodes, acc), cost in zip(queries, got):
         assert_costs_equal(
             cost, reference.cost(nodes, acc),
-            context=f"[{backend}{executor_kw or ''}] nodes={sorted(nodes)} "
+            context=f"[{backend}] nodes={sorted(nodes)} "
                     f"glb={acc.glb_bytes} wbuf={acc.wbuf_bytes} "
                     f"shared={acc.shared} share={acc.weight_share_cores}")
 

@@ -70,16 +70,6 @@ def test_fuzz_corpus_parity(backend, jobs):
         assert_backend_parity(g, queries, backend, jobs)
 
 
-def test_jax_pallas_variant_matches_serial():
-    """The Pallas streaming-block kernel variant is bit-identical too."""
-    if not available_backends(include_serial=False):
-        pytest.skip("no non-serial backends")
-    if ("jax", 1) not in available_backends():
-        pytest.skip("jax not installed")
-    for label, g, queries in scheme_corpus():
-        assert_backend_parity(g, queries, "jax", pallas=True)
-
-
 def test_jax_executor_handles_empty_and_all_fallback_batches():
     if ("jax", 1) not in available_backends():
         pytest.skip("jax not installed")

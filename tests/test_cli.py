@@ -186,6 +186,19 @@ def test_module_entrypoint_subprocess():
     assert "rank" in proc.stdout and "best:" in proc.stdout
 
 
+def test_chip_smoke_refuses_to_run_without_a_tpu():
+    """``chip_smoke.py`` checks the device first and never carries on on
+    the CPU: nonzero exit, no result line."""
+    pytest.importorskip("jax")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "chip_smoke.py"], cwd=REPO_ROOT,
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.returncode != 0
+    assert "not a TPU" in proc.stderr
+    assert '"ok"' not in proc.stdout
+
+
 def test_explore_eval_jobs_matches_serial(tmp_path, capsys):
     serial_out = tmp_path / "serial.json"
     parallel_out = tmp_path / "parallel.json"

@@ -245,6 +245,95 @@ def test_backend_status_reports_why_unavailable(monkeypatch):
         make_executor("jax", 1)
 
 
+def test_jax_backend_resolves_whenever_jax_imports():
+    """An installed jax must give a working ``jax`` backend: the device path
+    may not drop to a skip because the kernel module broke."""
+    pytest.importorskip("jax")
+    assert backend_status("jax") == (True, "")
+
+
+def test_jax_status_reports_only_a_missing_jax(monkeypatch):
+    import sys
+
+    import repro.core.engine as engine
+
+    monkeypatch.setattr(engine, "_JAX_STATUS", None)
+    monkeypatch.setitem(sys.modules, "jax", None)
+    ok, detail = engine.jax_status()
+    assert not ok and detail.startswith("ModuleNotFoundError")
+
+
+def test_jax_status_raises_when_the_kernel_module_breaks(monkeypatch):
+    """Any import failure other than a missing jax is a bug, not an
+    unavailable backend."""
+    import sys
+
+    import repro.core.engine as engine
+    import repro.kernels
+
+    pytest.importorskip("jax")
+    monkeypatch.setattr(engine, "_JAX_STATUS", None)
+    monkeypatch.delattr(repro.kernels, "finish_batch", raising=False)
+    monkeypatch.setitem(sys.modules, "repro.kernels.finish_batch", None)
+    with pytest.raises(ImportError):
+        engine.jax_status()
+    assert engine._JAX_STATUS is None
+
+
+_CACHE_PROBE = """
+import os, sys
+import jax
+import numpy as np
+from repro.kernels import finish_batch
+# the cache is placed only off the CPU backend; steer that check here so
+# the CPU compile stands in for the accelerator's
+jax.default_backend = lambda: "tpu"
+lanes = np.arange(1, 9, dtype=np.int64)
+finish_batch.finish_cost_batch(lanes, lanes, lanes % 2 == 0, lanes, lanes,
+                               lanes % 3 == 0, np.ones(8, dtype=np.int64))
+path = finish_batch.compile_cache_dir()
+print(path)
+print(len(os.listdir(path)))
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "checkout"])
+def test_compile_cache_placement(tmp_path, from_env):
+    """The kernel's compiles land in ``$JAX_COMPILATION_CACHE_DIR`` when it
+    is set, else in the fixed ``.jax_cache/`` at the checkout root."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    pytest.importorskip("jax")
+    root = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, PYTHONPATH=str(root / "src"),
+               JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = root / ".jax_cache"
+    if from_env:
+        want = tmp_path / "jax-cache"
+        env["JAX_COMPILATION_CACHE_DIR"] = str(want)
+    out = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr
+    path, n_entries = out.stdout.split()
+    assert Path(path) == want
+    assert int(n_entries) > 0
+
+
+def test_no_compile_cache_on_the_cpu_backend():
+    pytest.importorskip("jax")
+    import jax
+
+    from repro.kernels import finish_batch
+
+    if jax.default_backend() != "cpu":
+        pytest.skip("checks the CPU backend")
+    assert finish_batch.compile_cache_dir() is None
+
+
 # ---------------------------------------------------------------------------
 # scalar-fallback guard boundaries (pinned exactly for vector and jax)
 # ---------------------------------------------------------------------------
@@ -297,6 +386,12 @@ def test_fallback_guard_boundary_noc_product():
     assert not needs_scalar_fallback(
         replace(ok, weight_total=edge - 1), acc1)
     assert needs_scalar_fallback(replace(ok, weight_total=edge), acc1)
+    # with no weights the share count itself stays below 2**31 (the device
+    # kernel divides in int32)
+    no_w = replace(ok, weight_total=0)
+    assert not needs_scalar_fallback(no_w, replace(acc1,
+                                                   weight_share_cores=edge - 1))
+    assert needs_scalar_fallback(no_w, replace(acc1, weight_share_cores=edge))
 
 
 @pytest.mark.parametrize("backend,jobs", backend_params())

@@ -38,7 +38,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.configs import get_config
         from repro.models import lm_init, param_values, is_param
         from repro.parallel.sharding import mesh_context, logical_sharding
-        from repro.launch.mesh import rules_for
+        from repro.launch.mesh import make_mesh, rules_for
         from repro.train import AdamWConfig, adamw_init
         from repro.train.trainstep import make_train_step
         from repro.data import DataConfig, SyntheticLM
@@ -56,7 +56,7 @@ def test_sharded_train_step_matches_single_device():
         p1, o1, m1 = jax.jit(step)(values, opt, batch)
 
         # 4x2 (data, model) mesh
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         rules = rules_for(cfg, 'train')
         with mesh, mesh_context(mesh, rules):
             ptree = jax.eval_shape(lambda: lm_init(jax.random.PRNGKey(0), cfg))
@@ -130,9 +130,10 @@ def test_tripaware_collective_counting():
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as PS
+        from repro.launch.mesh import make_mesh
         from repro.launch.roofline import (collective_bytes,
                                            collective_bytes_tripaware)
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         w1 = jax.device_put(jnp.ones((16, 64, 64)),
                             NamedSharding(mesh, PS(None, None, 'model')))
         def f(x, w1):
