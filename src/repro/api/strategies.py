@@ -161,7 +161,6 @@ def run(spec: ExploreSpec, graph: Optional[Graph] = None,
             if (g_check is None
                     or cached.meta.get("graph_sha")
                     in (None, graph_fingerprint(g_check))):
-                obs.add("store.hit")
                 return cached
     if graph is not None:
         g = graph

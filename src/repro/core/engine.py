@@ -281,6 +281,8 @@ class _BatchedFinishExecutor(Executor):
     capacity/streaming/weight-sharing arithmetic itself.  Keeping one
     packing/stitching path means a new array backend cannot diverge from
     ``vector`` anywhere except inside the arithmetic the parity tests pin.
+    A batch records its phases as the spans ``executor.structures``,
+    ``executor.pack``, ``executor.finish`` and ``executor.stitch``.
     """
 
     def _finish_arrays(self, fp, w_total, single, glb, wbuf, shared, share):
@@ -297,60 +299,66 @@ class _BatchedFinishExecutor(Executor):
         import numpy as np
 
         queries = list(queries)
-        results: List[Optional[SubgraphCost]] = [None] * len(queries)
-        structs = [kernel.structure(nodes) for nodes, _ in queries]
-        vec_idx = []
-        for i, ((_, acc), st) in enumerate(zip(queries, structs)):
-            if needs_scalar_fallback(st, acc):
-                results[i] = finish_cost(st, acc)  # scalar fallback
-            else:
-                vec_idx.append(i)
-        n_fallback = len(queries) - len(vec_idx)
-        if n_fallback:
-            obs.add("engine.scalar_fallback", n_fallback)
-        if not vec_idx:
-            return results  # type: ignore[return-value]
+        rec = obs.current()
+        with rec.span("executor.structures", queries=len(queries)):
+            structs = [kernel.structure(nodes) for nodes, _ in queries]
+        with rec.span("executor.pack", queries=len(queries)):
+            results: List[Optional[SubgraphCost]] = [None] * len(queries)
+            vec_idx = []
+            for i, ((_, acc), st) in enumerate(zip(queries, structs)):
+                if needs_scalar_fallback(st, acc):
+                    results[i] = finish_cost(st, acc)  # scalar fallback
+                else:
+                    vec_idx.append(i)
+            n_fallback = len(queries) - len(vec_idx)
+            if n_fallback:
+                rec.add("engine.scalar_fallback", n_fallback)
+            if not vec_idx:
+                return results  # type: ignore[return-value]
 
-        sts = [structs[i] for i in vec_idx]
-        accs = [queries[i][1] for i in vec_idx]
-        fp = np.array([s.footprint for s in sts], dtype=np.int64)
-        w_total = np.array([s.weight_total for s in sts], dtype=np.int64)
-        single = np.array([len(s.nodes) == 1 for s in sts], dtype=bool)
-        glb = np.array([a.glb_bytes for a in accs], dtype=np.int64)
-        wbuf = np.array([a.wbuf_bytes for a in accs], dtype=np.int64)
-        shared = np.array([a.shared for a in accs], dtype=bool)
-        # construction validates weight_share_cores >= 1, no clamp needed
-        share = np.array([a.weight_share_cores for a in accs], dtype=np.int64)
+            sts = [structs[i] for i in vec_idx]
+            accs = [queries[i][1] for i in vec_idx]
+            fp = np.array([s.footprint for s in sts], dtype=np.int64)
+            w_total = np.array([s.weight_total for s in sts], dtype=np.int64)
+            single = np.array([len(s.nodes) == 1 for s in sts], dtype=bool)
+            glb = np.array([a.glb_bytes for a in accs], dtype=np.int64)
+            wbuf = np.array([a.wbuf_bytes for a in accs], dtype=np.int64)
+            shared = np.array([a.shared for a in accs], dtype=bool)
+            # construction validates weight_share_cores >= 1, no clamp needed
+            share = np.array([a.weight_share_cores for a in accs],
+                             dtype=np.int64)
 
-        (wr, n_blocks, ema_w, fp_out, noc, infeasible_buf, w_overflow,
-         stream, feasible) = self._finish_arrays(fp, w_total, single, glb,
-                                                 wbuf, shared, share)
+        with rec.span("executor.finish", lanes=len(vec_idx)):
+            (wr, n_blocks, ema_w, fp_out, noc, infeasible_buf, w_overflow,
+             stream, feasible) = self._finish_arrays(fp, w_total, single, glb,
+                                                     wbuf, shared, share)
 
-        for j, i in enumerate(vec_idx):
-            st = sts[j]
-            if infeasible_buf[j]:
-                reason = ("shared buffer overflow" if shared[j]
-                          else "global buffer overflow")
-            elif w_overflow[j]:
-                reason = "weight buffer overflow"
-            elif stream[j]:
-                reason = f"{STREAM_REASON} in {int(n_blocks[j])} blocks"
-            else:
-                reason = ""
-            results[i] = SubgraphCost(
-                nodes=st.nodes,
-                ema_in=st.ema_in,
-                ema_out=st.ema_out,
-                ema_w=int(ema_w[j]),
-                macs=st.macs,
-                footprint=int(fp_out[j]),
-                weight_resident=int(wr[j]),
-                glb_access_bytes=st.glb_access_bytes,
-                wbuf_access_bytes=int(wr[j]),
-                noc_bytes=int(noc[j]),
-                feasible=bool(feasible[j]),
-                reason=reason,
-            )
+        with rec.span("executor.stitch", lanes=len(vec_idx)):
+            for j, i in enumerate(vec_idx):
+                st = sts[j]
+                if infeasible_buf[j]:
+                    reason = ("shared buffer overflow" if shared[j]
+                              else "global buffer overflow")
+                elif w_overflow[j]:
+                    reason = "weight buffer overflow"
+                elif stream[j]:
+                    reason = f"{STREAM_REASON} in {int(n_blocks[j])} blocks"
+                else:
+                    reason = ""
+                results[i] = SubgraphCost(
+                    nodes=st.nodes,
+                    ema_in=st.ema_in,
+                    ema_out=st.ema_out,
+                    ema_w=int(ema_w[j]),
+                    macs=st.macs,
+                    footprint=int(fp_out[j]),
+                    weight_resident=int(wr[j]),
+                    glb_access_bytes=st.glb_access_bytes,
+                    wbuf_access_bytes=int(wr[j]),
+                    noc_bytes=int(noc[j]),
+                    feasible=bool(feasible[j]),
+                    reason=reason,
+                )
         return results  # type: ignore[return-value]
 
 

@@ -375,16 +375,19 @@ def evaluate_genomes(g: Graph, genomes: Sequence[Genome], obj: Objective,
     """
     if not genomes:
         return
-    repaired = split_to_fit_batch(
-        g, [(genome.groups, genome.acc) for genome in genomes], ev)
-    for genome, groups in zip(genomes, repaired):
-        genome.groups = groups
-        genome.invalidate()  # repair rebound groups; drop the stale index
-    plans = ev.plan_batch([(genome.groups, genome.acc)
-                           for genome in genomes])
-    for genome, plan in zip(genomes, plans):
-        genome.plan = plan
-        genome.cost = obj.cost(plan, genome.acc)
+    rec = obs.current()
+    with rec.span("ga.repair", genomes=len(genomes)):
+        repaired = split_to_fit_batch(
+            g, [(genome.groups, genome.acc) for genome in genomes], ev)
+        for genome, groups in zip(genomes, repaired):
+            genome.groups = groups
+            genome.invalidate()  # repair rebound groups; drop the stale index
+    with rec.span("ga.score", genomes=len(genomes)):
+        plans = ev.plan_batch([(genome.groups, genome.acc)
+                               for genome in genomes])
+        for genome, plan in zip(genomes, plans):
+            genome.plan = plan
+            genome.cost = obj.cost(plan, genome.acc)
 
 
 def run_ga(
@@ -404,26 +407,27 @@ def run_ga(
 ) -> SearchResult:
     rng = random.Random(seed)
     ev = ev or CachedEvaluator(g, out_tile=out_tile)
+    rec = obs.current()
 
     pop: List[Genome] = []
-    if init_groups:
-        for gr in init_groups[: population]:
-            pop.append(Genome([set(s) for s in gr], hw.sample(rng)))
-    while len(pop) < population:
-        mode = rng.random()
-        if mode < 0.2:
-            groups = singleton_partition(g)
-        else:
-            groups = random_partition(g, rng,
-                                      mean_size=rng.uniform(1.5, 6.0))
-        pop.append(Genome(groups, hw.sample(rng)))
+    with rec.span("ga.init", population=population):
+        if init_groups:
+            for gr in init_groups[: population]:
+                pop.append(Genome([set(s) for s in gr], hw.sample(rng)))
+        while len(pop) < population:
+            mode = rng.random()
+            if mode < 0.2:
+                groups = singleton_partition(g)
+            else:
+                groups = random_partition(g, rng,
+                                          mean_size=rng.uniform(1.5, 6.0))
+            pop.append(Genome(groups, hw.sample(rng)))
 
     samples = 0
     history: List[Tuple[int, float]] = []
     pop_log: List[List[Tuple[int, float, float]]] = []
     best: Optional[Genome] = None
 
-    rec = obs.current()
     with rec.span("ga.generation", gen=0, population=len(pop)):
         evaluate_genomes(g, pop, objective, ev)
     for ind in pop:
@@ -433,24 +437,25 @@ def run_ga(
             best.cost, best.plan = ind.cost, ind.plan
         history.append((samples, best.cost))
     if rec.enabled:
-        _emit_generation_telemetry(rec, best, pop, pop)
+        with rec.span("ga.samples"):
+            _emit_generation_telemetry(rec, best, pop, pop)
 
     gen = 0
     while samples < sample_budget:
         gen += 1
         with rec.span("ga.generation", gen=gen, samples=samples):
-            # --- variation ---------------------------------------------
-            offspring: List[Genome] = []
             n_children = population
-            for _ in range(n_children):
-                if rng.random() < crossover_frac and len(pop) >= 2:
-                    mom, dad = rng.sample(pop, 2)
-                    child = crossover(g, mom, dad, hw, rng)
-                    if rng.random() < 0.5:
-                        child = mutate(g, child, hw, rng)
-                else:
-                    child = mutate(g, rng.choice(pop), hw, rng)
-                offspring.append(child)
+            with rec.span("ga.variation", children=n_children):
+                offspring: List[Genome] = []
+                for _ in range(n_children):
+                    if rng.random() < crossover_frac and len(pop) >= 2:
+                        mom, dad = rng.sample(pop, 2)
+                        child = crossover(g, mom, dad, hw, rng)
+                        if rng.random() < 0.5:
+                            child = mutate(g, child, hw, rng)
+                    else:
+                        child = mutate(g, rng.choice(pop), hw, rng)
+                    offspring.append(child)
 
             # --- evaluation: one engine batch per generation ------------
             # the budget cap is known up front (evaluation spends one
@@ -466,22 +471,26 @@ def run_ga(
                 history.append((samples, best.cost))
 
             # --- tournament selection over parents + offspring ----------
-            pool = pop + evaluated
-            new_pop: List[Genome] = sorted(pool, key=lambda i: i.cost)[:elite]
-            while len(new_pop) < population:
-                contenders = rng.sample(pool, min(tournament_k, len(pool)))
-                new_pop.append(min(contenders, key=lambda i: i.cost))
-            pop = new_pop
-            if log_populations:
-                pop_log.append([
-                    (float(i.acc.buf_size_total),
-                     float(i.plan.metric(objective.metric))
-                     if i.plan else math.inf,
-                     i.cost)
-                    for i in pop
-                ])
+            with rec.span("ga.select"):
+                pool = pop + evaluated
+                new_pop: List[Genome] = sorted(
+                    pool, key=lambda i: i.cost)[:elite]
+                while len(new_pop) < population:
+                    contenders = rng.sample(pool,
+                                            min(tournament_k, len(pool)))
+                    new_pop.append(min(contenders, key=lambda i: i.cost))
+                pop = new_pop
+                if log_populations:
+                    pop_log.append([
+                        (float(i.acc.buf_size_total),
+                         float(i.plan.metric(objective.metric))
+                         if i.plan else math.inf,
+                         i.cost)
+                        for i in pop
+                    ])
         if rec.enabled:
-            _emit_generation_telemetry(rec, best, evaluated, pop)
+            with rec.span("ga.samples"):
+                _emit_generation_telemetry(rec, best, evaluated, pop)
 
     return SearchResult(best=best, history=history, population_log=pop_log,
                         samples=samples, evaluations=ev.evaluations)

@@ -40,6 +40,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import recorder as obs
+
 # fixed in-checkout cache path: the directory is part of the cache key, so
 # it must not move between runs
 _CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
@@ -67,6 +69,8 @@ def compile_cache_dir() -> Optional[str]:
     return path
 
 
+# the profiler trace names this kernel jit__finish_jnp, and the benchmark's
+# trace reduction finds it by that name: renaming it blanks its trace metrics
 @jax.jit
 def _finish_jnp(fp, w_total, single, glb, wbuf, shared, share):
     """Whole-batch ``finish_cost`` arithmetic as one jitted jnp expression.
@@ -100,15 +104,11 @@ def _finish_jnp(fp, w_total, single, glb, wbuf, shared, share):
             stream, feasible)
 
 
-def _pad_pow2(arr: np.ndarray, fill) -> np.ndarray:
-    n = len(arr)
-    m = 1
-    while m < n:
-        m *= 2
-    if m == n:
+def _pad(arr: np.ndarray, m: int, fill) -> np.ndarray:
+    if m == len(arr):
         return arr
     out = np.full(m, fill, dtype=arr.dtype)
-    out[:n] = arr
+    out[:len(arr)] = arr
     return out
 
 
@@ -121,6 +121,13 @@ def finish_cost_batch(fp, w_total, single, glb, wbuf, shared,
     guards.  Returns ``(wr, n_blocks, ema_w, fp_out, noc, infeasible_buf,
     w_overflow, stream, feasible)`` as NumPy arrays, bit-identical to the
     scalar kernel and to :class:`repro.core.engine.VectorExecutor`.
+
+    A call records three host spans, each with ``lanes`` and ``padded``:
+    ``executor.put`` (padding, and enqueueing the 7 inputs' transfer),
+    ``executor.run`` (dispatching the kernel) and ``executor.fetch``
+    (waiting for the kernel and the 9 outputs' transfer back).  Nothing
+    waits between them, so the transfer in overlaps the dispatch, and the
+    call is the same with and without a recorder.
     """
     n = len(fp)
     if n == 0:
@@ -128,18 +135,23 @@ def finish_cost_batch(fp, w_total, single, glb, wbuf, shared,
         empty_b = np.zeros(0, dtype=bool)
         return (empty_i,) * 5 + (empty_b,) * 4
     compile_cache_dir()
-    # pad to the next power of two: neutral lanes (glb/share=1 avoids any
-    # divide-by-zero path) that the element-wise arithmetic cannot couple
-    # into real lanes
-    args = (
-        _pad_pow2(np.asarray(fp, dtype=np.int64), 0),
-        _pad_pow2(np.asarray(w_total, dtype=np.int64), 0),
-        _pad_pow2(np.asarray(single, dtype=bool), False),
-        _pad_pow2(np.asarray(glb, dtype=np.int64), 1),
-        _pad_pow2(np.asarray(wbuf, dtype=np.int64), 1),
-        _pad_pow2(np.asarray(shared, dtype=bool), False),
-        _pad_pow2(np.asarray(share, dtype=np.int64), 1),
-    )
+    m = 1 << (n - 1).bit_length()  # the next power of two
+    rec = obs.current()
     with jax.enable_x64(True):
-        outs = _finish_jnp(*(jnp.asarray(a) for a in args))
-        return tuple(np.asarray(o)[:n] for o in outs)
+        with rec.span("executor.put", lanes=n, padded=m):
+            # pad to the next power of two: neutral lanes (glb/share=1
+            # avoids any divide-by-zero path) that the element-wise
+            # arithmetic cannot couple into real lanes
+            args = tuple(jnp.asarray(a) for a in (
+                _pad(np.asarray(fp, dtype=np.int64), m, 0),
+                _pad(np.asarray(w_total, dtype=np.int64), m, 0),
+                _pad(np.asarray(single, dtype=bool), m, False),
+                _pad(np.asarray(glb, dtype=np.int64), m, 1),
+                _pad(np.asarray(wbuf, dtype=np.int64), m, 1),
+                _pad(np.asarray(shared, dtype=bool), m, False),
+                _pad(np.asarray(share, dtype=np.int64), m, 1),
+            ))
+        with rec.span("executor.run", lanes=n, padded=m):
+            outs = _finish_jnp(*args)
+        with rec.span("executor.fetch", lanes=n, padded=m):
+            return tuple(np.asarray(o)[:n] for o in outs)

@@ -2,9 +2,10 @@
 
 The recorder is the single substrate every layer emits into: the search
 front door (``resolve-workload`` / ``strategy:<name>`` spans), the GA loop
-(per-generation spans plus best/mean/diversity samples), the batched
-engine (``evaluate_batch`` / executor submit+join spans, scalar-fallback
-counters), the partition repair loop, and the structure-memo tiers.
+(per-generation and per-phase spans plus best/mean/diversity samples), the
+batched engine (``evaluate_batch`` / ``executor.*`` phase and device-call
+spans, scalar-fallback counters), the partition repair loop, and the
+structure-memo tiers.
 
 Design constraints (the hard invariant carried from PRs 7-9):
 

@@ -233,26 +233,95 @@ def test_store_writes_identical_bytes_with_telemetry_on(tmp_path):
 # pinned span-tree shape + per-generation samples for a seeded GA run
 # ---------------------------------------------------------------------------
 
+def _names(nodes):
+    return [n["name"] for n in nodes]
+
+
+def _parents_of(tree, name, parent=None):
+    """Names of the parents of every span called ``name``, in tree order."""
+    out = []
+    for node in tree:
+        if node["name"] == name:
+            out.append(parent)
+        out += _parents_of(node["children"], name, node["name"])
+    return out
+
+
 def test_ga_span_tree_shape_is_pinned():
     rec = Recorder()
     with obs.recording(rec):
         run(ga_spec(), store=None)
     tree = rec.span_tree()
-    assert [n["name"] for n in tree] == ["resolve-workload", "strategy:ga"]
-    gens = tree[1]["children"]
-    assert [n["name"] for n in gens] == ["ga.generation"] * 15
-    # generation 0 evaluates the seed population plus repaired variants
-    assert [c["name"] for c in gens[0]["children"]] == \
-        ["evaluate_batch", "evaluate_batch"]
-    # every generation with cache misses nests its batch under itself
-    for gen in gens:
-        assert all(c["name"] == "evaluate_batch" for c in gen["children"])
+    assert _names(tree) == ["resolve-workload", "strategy:ga"]
+    # the population is built before generation 0, and each generation's
+    # samples are emitted after it closes: the phases tile the search
+    phases = tree[1]["children"]
+    assert _names(phases) == ["ga.init"] + ["ga.generation",
+                                            "ga.samples"] * 15
+    assert phases[0]["children"] == []
+    assert all(p["children"] == [] for p in phases[2::2])
+    gens = phases[1::2]
+    # generation 0 evaluates the seed population: one repair batch, then
+    # one costing batch of the repaired variants
+    assert _names(gens[0]["children"]) == ["ga.repair", "ga.score"]
+    assert [_names(c["children"]) for c in gens[0]["children"]] == \
+        [["evaluate_batch"], ["evaluate_batch"]]
+    for gen in gens[1:]:
+        assert _names(gen["children"]) == \
+            ["ga.variation", "ga.repair", "ga.score", "ga.select"]
+        variation, repair, score, select = gen["children"]
+        assert variation["children"] == [] and select["children"] == []
+        # every generation with cache misses nests its batches in a phase
+        for phase in (repair, score):
+            assert all(c["name"] == "evaluate_batch"
+                       for c in phase["children"])
+    assert set(_parents_of(tree, "evaluate_batch")) == {"ga.repair",
+                                                         "ga.score"}
     series = {name for name, _, _ in rec.samples}
     assert series == {"ga.best_cost", "ga.mean_cost", "ga.diversity"}
     n_best = sum(1 for name, _, _ in rec.samples if name == "ga.best_cost")
     assert n_best == len(gens)
     assert rec.counters["evaluator.lookups"] > 0
     assert rec.counters["repair.rounds"] > 0
+
+
+def test_jax_executor_span_tree_is_pinned():
+    """Each array batch of the ``jax`` executor records its phases, and
+    each device call its transfers and kernel, without perturbing the
+    result."""
+    pytest.importorskip("jax")
+    spec = golden_spec("synthetic_layered24", "ga")
+    plain = run(spec, eval_backend="jax")
+    rec = Recorder()
+    with obs.recording(rec):
+        recorded = run(spec, eval_backend="jax")
+    assert recorded.to_json() == plain.to_json()
+    batches = [sp for sp in rec.spans if sp.name == "evaluate_batch"]
+    assert batches
+    children = {sp.index: [] for sp in rec.spans}
+    for sp in rec.spans:
+        if sp.parent >= 0:
+            children[sp.parent].append(sp.name)
+    for sp in batches:
+        assert children[sp.index] in (
+            ["executor.structures", "executor.pack", "executor.finish",
+             "executor.stitch"],
+            # every lane took the scalar path: no device call
+            ["executor.structures", "executor.pack"])
+    finishes = [sp for sp in rec.spans if sp.name == "executor.finish"]
+    assert finishes
+    for sp in finishes:
+        assert children[sp.index] == \
+            ["executor.put", "executor.run", "executor.fetch"]
+        assert rec.spans[sp.parent].name == "evaluate_batch"
+    assert len(finishes) == rec.counters["engine.device_calls"]
+    assert sum(sp.attrs["lanes"] for sp in finishes) == \
+        rec.counters["engine.device_lanes"]
+    for sp in rec.spans:
+        if sp.name in ("executor.put", "executor.run", "executor.fetch"):
+            lanes, padded = sp.attrs["lanes"], sp.attrs["padded"]
+            assert lanes <= padded < 2 * lanes
+            assert padded & (padded - 1) == 0
 
 
 # ---------------------------------------------------------------------------

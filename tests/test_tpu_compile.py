@@ -60,3 +60,14 @@ def test_finish_kernel_compiles_for_v5e(batch, one_chip, no_persistent_cache):
     assert len(outs) == 9
     assert all(o.shape == (batch,) for o in outs)
     assert [o.dtype for o in outs] == [jnp.int64] * 5 + [jnp.bool_] * 4
+
+
+def test_finish_kernel_keeps_its_trace_name():
+    """The profiler trace names a jitted program after its lowered module,
+    and the benchmark's trace reduction finds the kernel as
+    ``jit__finish_jnp``: a rename would blank the kernel's trace metrics."""
+    with jax.enable_x64(True):
+        i64 = jax.ShapeDtypeStruct((8,), jnp.int64)
+        b = jax.ShapeDtypeStruct((8,), jnp.bool_)
+        text = _finish_jnp.lower(i64, i64, b, i64, i64, b, i64).as_text()
+    assert text.startswith("module @jit__finish_jnp ")
