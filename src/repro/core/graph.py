@@ -79,6 +79,8 @@ class Graph:
         # topo_order() memo (a tuple, so the shared value is mutation-proof);
         # invalidated by length whenever add_node grows the graph
         self._topo: Optional[Tuple[int, ...]] = None
+        # edge_ends() memo, invalidated by length whenever add_edge grows it
+        self._ends: Optional[Tuple[Tuple[int, ...], Tuple[int, ...]]] = None
 
     # -- construction -----------------------------------------------------
     def add_node(
@@ -153,6 +155,16 @@ class Graph:
             t = self._topo = tuple(range(len(self.nodes)))
         return t
 
+    def edge_ends(self) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
+        """``(srcs, dsts)``: the endpoints of ``edges`` as two flat int tuples,
+        in edge order (memoized; normalize maps group ids over them once
+        per call)."""
+        ends = self._ends
+        if ends is None or len(ends[0]) != len(self.edges):
+            ends = self._ends = (tuple(e.src for e in self.edges),
+                                 tuple(e.dst for e in self.edges))
+        return ends
+
     # -- subgraph helpers ---------------------------------------------------
     #
     # These iterate the subgraph's own adjacency lists (O(sum of member
@@ -200,8 +212,19 @@ class Graph:
         if len(nodes) == 1:  # fast path: most GA groups are singletons
             return [set(nodes)]
         remaining = set(nodes)
-        comps: List[Set[int]] = []
         und = self._und
+        if len(nodes) == 2:  # the walk below, unrolled: it adds x, then y
+            x, y = remaining
+            return [{x, y}] if y in und[x] else [{x}, {y}]
+        comps: List[Set[int]] = []
+        # Members already in `comp` are pushed too and skipped on pop, so
+        # nodes are added to `comp` in the order a walk that filtered them
+        # out would add them.  That order fixes the set's iteration order,
+        # which later splits of the set read (the root of each component),
+        # so it is kept.  Neighbours of an earlier component are never
+        # reachable: filtering against `remaining` equals filtering against
+        # the full node set.
+        member = remaining.__contains__
         while remaining:
             root = next(iter(remaining))
             comp = set()
@@ -211,11 +234,7 @@ class Graph:
                 if v in comp:
                     continue
                 comp.add(v)
-                # neighbours of an earlier component are never reachable, so
-                # filtering against `remaining` equals filtering against the
-                # full node set
-                stack.extend(w for w in und[v]
-                             if w in remaining and w not in comp)
+                stack.extend(filter(member, und[v]))
             comps.append(comp)
             remaining -= comp
         return comps

@@ -13,6 +13,8 @@ are split during evaluation instead of discarding the genome.
 
 from __future__ import annotations
 
+import heapq
+import operator
 import random
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
@@ -51,29 +53,40 @@ def is_valid(g: Graph, P: Sequence[int]) -> bool:
     return True
 
 
-def _quotient_edges(g: Graph, gid: Sequence[int]) -> Set[Tuple[int, int]]:
-    q = set()
-    for e in g.edges:
-        a, b = gid[e.src], gid[e.dst]
-        if a < 0 or b < 0:
-            raise ValueError(
-                f"groups do not cover node {e.src if a < 0 else e.dst}")
-        if a != b:
-            q.add((a, b))
-    return q
+def _group_ids(n: int, groups: Sequence[Set[int]]) -> List[int]:
+    """Node -> index of the last group that holds it; -1 = uncovered."""
+    gid = [-1] * n
+    for i, s in enumerate(groups):
+        for v in s:
+            gid[v] = i
+    return gid
 
 
-def _topo_order_quotient(n_groups: int,
-                         qedges: Set[Tuple[int, int]]) -> Optional[List[int]]:
-    """Kahn, smallest id first (a min-heap pops the same order the previous
-    sort-per-iteration implementation did); None if cyclic."""
-    import heapq
+def _quotient_ends(g: Graph, gid: Sequence[int]
+                   ) -> Tuple[List[int], List[int]]:
+    """Group ids of every edge's source and destination, in edge order."""
+    srcs, dsts = g.edge_ends()
+    a, b = list(map(gid.__getitem__, srcs)), list(map(gid.__getitem__, dsts))
+    if -1 in a or -1 in b:
+        for u, v, ga in zip(srcs, dsts, a):
+            if ga < 0 or gid[v] < 0:
+                raise ValueError(
+                    f"groups do not cover node {u if ga < 0 else v}")
+    return a, b
 
+
+def _kahn(n_groups: int, a: Sequence[int], b: Sequence[int]
+          ) -> Tuple[List[int], List[int]]:
+    """Kahn over the quotient edges ``a[j] -> b[j]``, smallest id first (the
+    lexicographically least topological order, whatever the order of the
+    edges).  Returns ``(order, indeg)``: ``order`` holds every id iff the
+    quotient is acyclic; ids left out keep a non-zero ``indeg``."""
     indeg = [0] * n_groups
-    out: Dict[int, List[int]] = {i: [] for i in range(n_groups)}
-    for a, b in qedges:
-        out[a].append(b)
-        indeg[b] += 1
+    out: List[List[int]] = [[] for _ in range(n_groups)]
+    for x, y in zip(a, b):
+        if x != y:
+            out[x].append(y)
+            indeg[y] += 1
     heap = [i for i in range(n_groups) if indeg[i] == 0]
     heapq.heapify(heap)
     order = []
@@ -84,7 +97,99 @@ def _topo_order_quotient(n_groups: int,
             indeg[w] -= 1
             if indeg[w] == 0:
                 heapq.heappush(heap, w)
-    return order if len(order) == n_groups else None
+    return order, indeg
+
+
+def _cycle(a: Sequence[int], b: Sequence[int], indeg: Sequence[int]
+           ) -> Set[int]:
+    """The ids of one quotient cycle, from the ids :func:`_kahn` left out:
+    each has a predecessor among them, so walking back from one repeats."""
+    back = {}
+    for x, y in zip(a, b):
+        if x != y and indeg[x] and indeg[y]:
+            back[y] = x
+    v = next(iter(back))
+    seen = set()
+    while v not in seen:
+        seen.add(v)
+        v = back[v]
+    cyc = {v}
+    u = back[v]
+    while u != v:
+        cyc.add(u)
+        u = back[u]
+    return cyc
+
+
+def _split_largest(g: Graph, groups: List[Set[int]]
+                   ) -> Optional[Tuple[Set[int], List[Set[int]]]]:
+    """One cycle-breaking step: split the largest multi-node group (first in
+    list order on ties) at its node-index median into the weak components
+    of the part below and of the rest; None if every group is a single."""
+    cand = max(groups, key=len, default=None)   # first of the largest
+    if cand is None or len(cand) == 1:
+        return None
+    med = sorted(cand)[len(cand) // 2]
+    pieces: List[Set[int]] = []
+    # both parts are non-empty: the median's index in sorted order is >= 1
+    for part in ({v for v in cand if v < med}, {v for v in cand if v >= med}):
+        if len(part) == 1:
+            pieces.append(part)
+        else:
+            pieces.extend(g.weakly_connected_components(part))
+    return cand, pieces
+
+
+def _break_cycles(g: Graph, groups: List[Set[int]], labels: List[int],
+                  cycle: Set[int]) -> List[Set[int]]:
+    """Split groups by :func:`_split_largest`, each step removing the split
+    group and appending its pieces, up to the first step whose quotient is
+    acyclic (at most ``g.n`` steps).  ``labels`` maps nodes to ids of
+    ``groups`` and is updated in place; ``cycle`` holds ids of a cycle.
+
+    Acyclicity is checked on node labels, not list positions: a step gives
+    only the split group's nodes fresh labels, one per piece (with groups
+    that share a node the last holds it, and so does the piece appended
+    last), so nothing is rebuilt.  A step that relabels no node of the
+    known cycle leaves that cycle's groups, and the edges between them, as
+    they were: it ends cyclic too and is not checked.  Only a step that
+    cuts into the cycle is checked, and a failed check names the next one.
+
+    (With disjoint groups a split never makes an acyclic quotient cyclic:
+    node index order is topological, ``src < dst``, so every edge between
+    the part below the median and the rest runs upwards, and a cycle
+    through the pieces would map onto one through the split group.  The
+    cycle argument above does not need that, so groups sharing nodes get
+    the same steps as before too.)
+    """
+    n = g.n
+    cur = list(groups)
+    n_labels = len(groups)
+    splits = 0
+    while True:
+        while True:     # the grouping after `splits` steps holds `cycle`
+            step = _split_largest(g, cur)
+            if step is None:
+                raise RuntimeError("cyclic quotient with singleton groups")
+            if splits == n:
+                raise RuntimeError("normalize did not converge")
+            cand, pieces = step
+            cut = not cycle.isdisjoint(map(labels.__getitem__, cand))
+            for lab, p in enumerate(pieces, n_labels):
+                for v in p:
+                    labels[v] = lab
+            n_labels += len(pieces)
+            cur.remove(cand)
+            cur.extend(pieces)
+            splits += 1
+            if cut:
+                break
+        a, b = _quotient_ends(g, labels)
+        order, indeg = _kahn(n_labels, a, b)
+        if len(order) == n_labels:
+            obs.add("normalize.cycle_splits", splits)
+            return cur
+        cycle = _cycle(a, b, indeg)
 
 
 def normalize(g: Graph, raw_groups: Sequence[Set[int]]) -> List[Set[int]]:
@@ -101,35 +206,21 @@ def normalize(g: Graph, raw_groups: Sequence[Set[int]]) -> List[Set[int]]:
         else:
             groups.extend(g.weakly_connected_components(set(s)))
 
-    # 2. break quotient cycles by topological bisection of offending groups
-    for _ in range(g.n + 1):
-        gid_arr = [-1] * g.n  # -1 = uncovered; _quotient_edges raises on it
-        for i, s in enumerate(groups):
-            for v in s:
-                gid_arr[v] = i
-        qedges = _quotient_edges(g, gid_arr)
-        order = _topo_order_quotient(len(groups), qedges)
-        if order is not None:
-            # renumber groups in quotient topological order
-            return [groups[i] for i in order]
-        # find a group on a cycle: any group with both in- and out-quotient
-        # edges to a common strongly-connected region; heuristic: split the
-        # largest multi-node group by node-index median
-        cand = max((s for s in groups if len(s) > 1), key=len, default=None)
-        if cand is None:
-            raise RuntimeError("cyclic quotient with singleton groups")
-        med = sorted(cand)[len(cand) // 2]
-        lo = {v for v in cand if v < med}
-        hi = {v for v in cand if v >= med}
-        groups.remove(cand)
-        for part in (lo, hi):
-            if not part:
-                continue
-            if len(part) == 1:
-                groups.append(part)
-            else:
-                groups.extend(g.weakly_connected_components(part))
-    raise RuntimeError("normalize did not converge")
+    # 2. already ordered: Kahn's smallest-id-first order is the identity
+    # exactly when no edge runs from a later group to an earlier one
+    gid = _group_ids(g.n, groups)
+    a, b = _quotient_ends(g, gid)
+    if not any(map(operator.gt, a, b)):
+        return groups
+    obs.add("normalize.reordered")
+    order, indeg = _kahn(len(groups), a, b)
+    if len(order) < len(groups):
+        # 3. break quotient cycles by splitting groups at their median
+        groups = _break_cycles(g, groups, gid, _cycle(a, b, indeg))
+        order, _ = _kahn(len(groups),
+                         *_quotient_ends(g, _group_ids(g.n, groups)))
+    # renumber groups in quotient topological order
+    return [groups[i] for i in order]
 
 
 def split_group_topo(g: Graph, s: Set[int], pieces: int = 2) -> List[Set[int]]:

@@ -159,3 +159,266 @@ def test_ga_history_monotone():
                  sample_budget=300, population=20, seed=3)
     costs = [c for _, c in res.history]
     assert all(b <= a + 1e-9 for a, b in zip(costs, costs[1:]))
+
+
+# ---------------------------------------------------------------------------
+# normalize against the implementation it replaced
+# ---------------------------------------------------------------------------
+#
+# `_ref_normalize` is the former `normalize`, with its helpers and the former
+# `Graph.weakly_connected_components` (`_ref_components`), copied unchanged
+# but for the components call.  The outputs must agree as lists of sets and
+# also in each set's iteration order: the GA and later normalize calls walk
+# groups in that order (a split group's root, its components' order), so
+# agreeing in both keeps every search on its former course.
+
+def _ref_components(g, nodes):
+    if len(nodes) == 1:  # fast path: most GA groups are singletons
+        return [set(nodes)]
+    remaining = set(nodes)
+    comps = []
+    und = g._und
+    while remaining:
+        root = next(iter(remaining))
+        comp = set()
+        stack = [root]
+        while stack:
+            v = stack.pop()
+            if v in comp:
+                continue
+            comp.add(v)
+            # neighbours of an earlier component are never reachable, so
+            # filtering against `remaining` equals filtering against the
+            # full node set
+            stack.extend(w for w in und[v]
+                         if w in remaining and w not in comp)
+        comps.append(comp)
+        remaining -= comp
+    return comps
+
+
+def _ref_quotient_edges(g, gid):
+    q = set()
+    for e in g.edges:
+        a, b = gid[e.src], gid[e.dst]
+        if a < 0 or b < 0:
+            raise ValueError(
+                f"groups do not cover node {e.src if a < 0 else e.dst}")
+        if a != b:
+            q.add((a, b))
+    return q
+
+
+def _ref_topo_order_quotient(n_groups, qedges):
+    """Kahn, smallest id first (a min-heap pops the same order the previous
+    sort-per-iteration implementation did); None if cyclic."""
+    import heapq
+
+    indeg = [0] * n_groups
+    out = {i: [] for i in range(n_groups)}
+    for a, b in qedges:
+        out[a].append(b)
+        indeg[b] += 1
+    heap = [i for i in range(n_groups) if indeg[i] == 0]
+    heapq.heapify(heap)
+    order = []
+    while heap:
+        v = heapq.heappop(heap)
+        order.append(v)
+        for w in out[v]:
+            indeg[w] -= 1
+            if indeg[w] == 0:
+                heapq.heappush(heap, w)
+    return order if len(order) == n_groups else None
+
+
+def _ref_normalize(g, raw_groups):
+    """Repair arbitrary groups into a valid ordered partition."""
+    groups = []
+    for s in raw_groups:
+        if not s:
+            continue
+        if len(s) == 1:
+            groups.append(set(s))
+        else:
+            groups.extend(_ref_components(g, set(s)))
+
+    for _ in range(g.n + 1):
+        gid_arr = [-1] * g.n  # -1 = uncovered; _quotient_edges raises on it
+        for i, s in enumerate(groups):
+            for v in s:
+                gid_arr[v] = i
+        qedges = _ref_quotient_edges(g, gid_arr)
+        order = _ref_topo_order_quotient(len(groups), qedges)
+        if order is not None:
+            return [groups[i] for i in order]
+        cand = max((s for s in groups if len(s) > 1), key=len, default=None)
+        if cand is None:
+            raise RuntimeError("cyclic quotient with singleton groups")
+        med = sorted(cand)[len(cand) // 2]
+        lo = {v for v in cand if v < med}
+        hi = {v for v in cand if v >= med}
+        groups.remove(cand)
+        for part in (lo, hi):
+            if not part:
+                continue
+            if len(part) == 1:
+                groups.append(part)
+            else:
+                groups.extend(_ref_components(g, part))
+    raise RuntimeError("normalize did not converge")
+
+
+def _outcome(fn, g, raw):
+    """What ``fn`` gives: its groups with each set's iteration order, or the
+    error it raised."""
+    try:
+        return [list(s) for s in fn(g, [set(s) for s in raw])]
+    except (ValueError, RuntimeError) as err:
+        return (type(err).__name__, str(err))
+
+
+def _groupings(g, rng, count):
+    """Seeded raw groupings of every kind normalize meets, and a few more."""
+    n = g.n
+    for i in range(count):
+        kind = i % 6
+        if kind == 0:    # random labels: disconnected and cyclic groups
+            m = rng.randint(1, max(1, n // 3))
+            lab = [rng.randrange(m) for _ in range(n)]
+            raw = [{v for v in range(n) if lab[v] == j} for j in range(m)]
+        elif kind == 1:  # valid, then shuffled: acyclic but out of order
+            raw = random_partition(g, rng, mean_size=rng.choice([2.0, 4.0]))
+            rng.shuffle(raw)
+        elif kind == 2:  # strided: cyclic, needing many split steps
+            m = rng.randint(2, 5)
+            raw = [{v for v in range(n) if v % m == j} for j in range(m)]
+            raw[0] |= {v for v in range(n) if rng.random() < 0.2}
+            raw = [s - raw[0] for s in raw[1:]] + [raw[0]]
+        elif kind == 3:  # few large random groups, with empty sets
+            m = rng.randint(2, 4)
+            lab = [rng.randrange(m) for _ in range(n)]
+            raw = [{v for v in range(n) if lab[v] == j} for j in range(m)]
+            raw.insert(rng.randrange(len(raw) + 1), set())
+            raw.append(set())
+        elif kind == 4:  # a node with an edge left out: uncovered
+            raw = [set(s) for s in random_partition(g, rng)]
+            e = g.edges[rng.randrange(len(g.edges))]
+            v = rng.choice([e.src, e.dst])
+            raw = [s - {v} for s in raw]
+        else:            # groups that share nodes
+            raw = [set(s) for s in random_partition(g, rng)]
+            for _ in range(rng.randint(1, 3)):
+                raw[rng.randrange(len(raw))] |= set(
+                    rng.sample(range(n), k=min(n, rng.randint(1, 4))))
+        yield raw
+
+
+@pytest.mark.parametrize("uri", ["netlib:resnet50",
+                                 "synthetic:branchy:64?seed=3", "diamond"])
+def test_normalize_matches_former_implementation(uri):
+    from repro.api import build_workload
+    from repro.obs import Recorder, recording
+
+    g = small_graph() if uri == "diamond" else build_workload(uri)
+    rng = random.Random(14)
+    seen = {"ValueError": 0, "splits": 0, "most_splits": 0, "reordered": 0}
+    for raw in _groupings(g, rng, 240):
+        rec = Recorder()
+        with recording(rec):
+            got = _outcome(normalize, g, raw)
+        assert got == _outcome(_ref_normalize, g, raw), raw
+        if isinstance(got, tuple):
+            seen[got[0]] = seen.get(got[0], 0) + 1
+        splits = rec.counters.get("normalize.cycle_splits", 0)
+        seen["splits"] += splits > 0
+        seen["most_splits"] = max(seen["most_splits"], splits)
+        seen["reordered"] += rec.counters.get("normalize.reordered", 0)
+    # the cases reach every path: uncovered nodes, reordering, and cycles
+    # broken in one step and in many
+    assert seen["ValueError"] >= 30
+    assert seen["reordered"] >= 60 and seen["splits"] >= 30
+    assert seen["most_splits"] >= (10 if g.n > 20 else 2)
+
+
+def test_normalize_follows_graph_growth():
+    g = small_graph()
+    raw = [{0, 2, 3}, {1}, {4, 5, 6, 7}]
+    assert _outcome(normalize, g, raw) == _outcome(_ref_normalize, g, raw)
+    assert g.edge_ends() == ((0, 0, 1, 2, 3, 4, 4, 5, 6),
+                             (1, 2, 3, 3, 4, 5, 6, 7, 7))
+    # a new node fed by 1 and feeding 7: the cached endpoints follow
+    v = g.add_node("n8", 32, 16)
+    g.add_edge(1, v)
+    assert g.edge_ends()[1][-1] == v
+    with pytest.raises(ValueError):   # insertion order stays topological
+        g.add_edge(v, 7)
+    w = g.add_node("n9", 32, 16)
+    g.add_edge(v, w)
+    g.add_edge(3, w)
+    assert g.edge_ends() == ((0, 0, 1, 2, 3, 4, 4, 5, 6, 1, 8, 3),
+                             (1, 2, 3, 3, 4, 5, 6, 7, 7, 8, 9, 9))
+    for raw in ([{0, 2, 3, 9}, {1, 8}, {4, 5, 6, 7}],
+                [{0, 1, 8, 9}, {2, 3, 4}, {5, 6, 7}],
+                [{8, 3}, {0, 1, 2}, {4, 5, 6, 7, 9}],
+                [{0, 2, 3}, {1}, {4, 5, 6, 7}]):     # node 8, 9 uncovered
+        assert _outcome(normalize, g, raw) == _outcome(_ref_normalize, g, raw)
+    assert _outcome(normalize, g, [{0, 2, 3}, {1}, {4, 5, 6, 7}]) == (
+        "ValueError", "groups do not cover node 8")
+
+
+@pytest.mark.parametrize("uri", ["netlib:resnet50",
+                                 "synthetic:branchy:64?seed=3"])
+def test_weakly_connected_components_match_plain_bfs(uri):
+    from collections import deque
+
+    from repro.api import build_workload
+
+    g = build_workload(uri)
+    adj = {v: set() for v in range(g.n)}
+    for e in g.edges:
+        adj[e.src].add(e.dst)
+        adj[e.dst].add(e.src)
+    rng = random.Random(5)
+    for _ in range(400):
+        nodes = set(rng.sample(range(g.n), k=rng.randint(1, min(g.n, 24))))
+        want, left = set(), set(nodes)
+        while left:
+            comp, todo = set(), deque([left.pop()])
+            while todo:
+                v = todo.popleft()
+                comp.add(v)
+                for w in adj[v] & left:
+                    left.discard(w)
+                    todo.append(w)
+            want.add(frozenset(comp))
+        got = g.weakly_connected_components(nodes)
+        assert {frozenset(c) for c in got} == want
+        assert len(got) == len(want)
+        # and the same sets, in the same list and iteration order, as before
+        assert [list(c) for c in got] == [
+            list(c) for c in _ref_components(g, nodes)]
+
+
+def test_normalize_counts_reordering_and_cycle_splits():
+    from repro.obs import Recorder, recording
+
+    g = small_graph()
+    rec = Recorder()
+    with recording(rec):
+        normalize(g, [{v} for v in range(g.n)])           # already ordered
+        normalize(g, [{0, 1, 2, 3}, {4, 5, 6, 7}])
+    assert "normalize.reordered" not in rec.counters
+    assert "normalize.cycle_splits" not in rec.counters
+
+    rec = Recorder()
+    with recording(rec):
+        normalize(g, [{4, 5, 6, 7}, {0, 1, 2, 3}])        # reordered only
+    assert rec.counters == {"normalize.reordered": 1}
+
+    rec = Recorder()
+    with recording(rec):
+        groups = normalize(g, [{0, 2, 3}, {1}, {4, 5, 6, 7}])  # 0->1->3
+    assert is_valid(g, partition_of(groups, g.n))
+    assert rec.counters["normalize.reordered"] == 1
+    assert rec.counters["normalize.cycle_splits"] >= 1
