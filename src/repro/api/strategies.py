@@ -144,7 +144,7 @@ def run(spec: ExploreSpec, graph: Optional[Graph] = None,
     (default ``$REPRO_STRUCT_CACHE_DIR``) adds a disk-backed warm tier for
     canonical structures when ``run`` builds the evaluator.
     """
-    from .workloads import workload_is_stable
+    from .workloads import workload_fingerprint
 
     use_store = store is not None and not runtime
     if use_store:
@@ -152,15 +152,12 @@ def run(spec: ExploreSpec, graph: Optional[Graph] = None,
         if cached is not None:
             # Store keys carry no graph identity, so refuse another graph's
             # artifact: a custom graph= shares only the workload *label*
-            # with the spec, and a non-stable workload URI (file: — the
-            # file can change under an unchanged URI) must be re-resolved
-            # and fingerprint-checked before its artifact replays.
-            g_check = graph
-            if g_check is None and not workload_is_stable(spec.workload):
-                g_check = graph = build_workload(spec.workload)
-            if (g_check is None
-                    or cached.meta.get("graph_sha")
-                    in (None, graph_fingerprint(g_check))):
+            # with the spec, and a URI may build another graph today than
+            # when the artifact was stored (a changed file: file, a netlib
+            # model the program now builds differently).
+            sha = (graph_fingerprint(graph) if graph is not None
+                   else workload_fingerprint(spec.workload))
+            if sha is None or cached.meta.get("graph_sha") in (None, sha):
                 return cached
     if graph is not None:
         g = graph

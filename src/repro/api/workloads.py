@@ -56,12 +56,14 @@ class WorkloadScheme:
     # scheme's instances are not enumerable, e.g. file:)
     expand_fn: Optional[Callable[[], List[str]]] = None
     # False when the URI does not pin the graph's content (file: — the file
-    # can change under an unchanged URI); the store layer then re-checks the
-    # graph fingerprint before replaying an artifact
+    # can change under an unchanged URI); workload_fingerprint then builds
+    # the graph anew on every store hit instead of once per process
     stable: bool = True
 
 
 _SCHEMES: Dict[str, WorkloadScheme] = {}
+# workload_fingerprint's digests of stable URIs, for this process
+_FINGERPRINTS: Dict[str, Optional[str]] = {}
 
 
 def register_workload_scheme(name: str, *, syntax: str, description: str,
@@ -75,10 +77,12 @@ def register_workload_scheme(name: str, *, syntax: str, description: str,
     reject unknown keys so that two spellings of one workload cannot alias
     different graphs).  Pass ``stable=False`` when the URI alone does not
     pin the graph's content (e.g. a path whose file can change): the store
-    layer then verifies the graph fingerprint before replaying artifacts.
+    layer then fingerprints the graph anew before every replay instead of
+    once per process.
     """
 
     def deco(fn: Callable[[str, Dict[str, str]], Graph]):
+        _FINGERPRINTS.clear()
         _SCHEMES[name] = WorkloadScheme(name=name, build=fn, syntax=syntax,
                                         description=description,
                                         list_fn=list_fn, expand_fn=expand_fn,
@@ -150,6 +154,33 @@ def workload_is_stable(uri: str) -> bool:
         return True
     entry = _SCHEMES.get(uri.split(":", 1)[0])
     return entry.stable if entry is not None else True
+
+
+def workload_fingerprint(uri: str) -> Optional[str]:
+    """:func:`~repro.api.store.graph_fingerprint` of the graph ``uri``
+    builds today, or None where a stable URI resolves nowhere (a free-form
+    label of a graph passed to ``run(graph=...)``).
+
+    The store layer checks every replayed artifact's ``graph_sha`` against
+    it: a ``file:`` can change under its URI, and a new release of the
+    program can rebuild a ``netlib:`` model.  A stable URI's digest is kept
+    for the process, so its hits cost one build per URI; a ``file:`` URI is
+    built on every call.
+    """
+    if uri in _FINGERPRINTS:
+        return _FINGERPRINTS[uri]
+    from .store import graph_fingerprint
+
+    stable = workload_is_stable(uri)
+    try:
+        sha = graph_fingerprint(build_workload(uri))
+    except (ValueError, KeyError, RuntimeError):
+        if not stable:
+            raise
+        sha = None
+    if stable:
+        _FINGERPRINTS[uri] = sha
+    return sha
 
 
 def build_workload(uri: str) -> Graph:

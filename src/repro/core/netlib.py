@@ -2,7 +2,9 @@
 
 plain:        VGG16 [57]
 multi-branch: ResNet50 / ResNet152 [20], GoogleNet [59], Transformer [64], GPT [52]
-irregular:    RandWire-A/B [68] (seeded Watts-Strogatz generators, networkx),
+irregular:    RandWire-A/B [68] (RandWire-WS small / regular regime of
+              arXiv:1904.01569 Table 2; Watts-Strogatz instances from
+              assumed seeds, networkx),
               NasNet-A [75]
 
 Modelling conventions (paper §5.1.1): FC layers are 1x1 convolutions; pooling
@@ -279,49 +281,70 @@ def gpt(layers: int = 12, d: int = 768, dff: int = 3072,
 # irregular: RandWire (Watts–Strogatz, seeded) and NasNet-A
 # ---------------------------------------------------------------------------
 
-def _randwire_stage(b: NetBuilder, x: int, n: int, k: int, p: float,
-                    c: int, stride: int, seed: int, tag: str) -> int:
+# RandWire-WS, Xie et al., "Exploring Randomly Wired Neural Networks for Image
+# Recognition", arXiv:1904.01569, Table 2.  The paper publishes the generator
+# law (Watts-Strogatz, K = 4, P = 0.75), not its instances: the seeds of each
+# stage's graph below are assumed.
+RANDWIRE_N, RANDWIRE_K, RANDWIRE_P = 32, 4, 0.75
+RANDWIRE_SEEDS = {"A": (11, 12, 13), "B": (21, 22, 23, 24)}
+
+
+def _randwire_stage(b: NetBuilder, x: int, n: int, c: int, seed: int,
+                    tag: str) -> int:
+    """One randomly wired stage of ``n`` nodes with ``c`` channels (paper
+    §3): a Watts-Strogatz graph with edges directed from the lower to the
+    higher index.  Each node sums its inputs (when it has more than one) and
+    applies a ReLU-SepConv3x3 (depthwise + pointwise); a node with no input
+    reads the stage input at stride 2; the stage output sums the nodes with
+    no output."""
     import networkx as nx
 
-    ws = nx.connected_watts_strogatz_graph(n, k, p, seed=seed)
-    order = sorted(ws.nodes())
-    # DAG orientation: edge (i, j) with i < j
-    ins: Dict[int, List[int]] = {i: [] for i in order}
-    outs: Dict[int, List[int]] = {i: [] for i in order}
+    ws = nx.connected_watts_strogatz_graph(n, RANDWIRE_K, RANDWIRE_P,
+                                           seed=seed)
+    ins: Dict[int, List[int]] = {i: [] for i in range(n)}
+    outs: Dict[int, List[int]] = {i: [] for i in range(n)}
     for (i, j) in ws.edges():
         i, j = min(i, j), max(i, j)
         ins[j].append(i)
         outs[i].append(j)
     nodes: Dict[int, int] = {}
-    for i in order:
-        srcs = [nodes[j] for j in ins[i]]
-        if not srcs:
-            # stage input node (stride applied here)
-            inp = b.conv(x, c, 3, stride, name=f"{tag}.n{i}.dw",
-                         depthwise=False)
-            nodes[i] = inp
-            continue
-        agg = srcs[0] if len(srcs) == 1 else b.eltwise(srcs, f"{tag}.n{i}.sum")
-        # ReLU-sepconv3x3: depthwise + pointwise
-        dw = b.conv(agg, 0, 3, 1, name=f"{tag}.n{i}.dw", depthwise=True)
-        pw = b.conv(dw, c, 1, 1, name=f"{tag}.n{i}.pw")
-        nodes[i] = pw
-    sinks = [nodes[i] for i in order if not outs[i]]
+    for i in range(n):
+        if ins[i]:
+            srcs = [nodes[j] for j in sorted(ins[i])]
+            agg = (srcs[0] if len(srcs) == 1
+                   else b.eltwise(srcs, f"{tag}.n{i}.sum"))
+            dw = b.conv(agg, 0, 3, 1, name=f"{tag}.n{i}.dw", depthwise=True)
+        else:
+            dw = b.conv(x, 0, 3, 2, name=f"{tag}.n{i}.dw", depthwise=True)
+        nodes[i] = b.conv(dw, c, 1, 1, name=f"{tag}.n{i}.pw")
+    sinks = [nodes[i] for i in range(n) if not outs[i]]
     return sinks[0] if len(sinks) == 1 else b.eltwise(sinks, f"{tag}.out")
 
 
 def randwire(variant: str = "A") -> Graph:
-    """RandWire-A (small regime, C=78) / RandWire-B (regular regime, C=109)."""
-    c = 78 if variant == "A" else 109
-    seed0 = 11 if variant == "A" else 23
+    """RandWire-WS of Table 2: A is the small regime (C = 78), B the
+    regular regime (C = 109).
+
+    Small: conv1 3x3/2 to C/2 at 112, conv2 3x3/2 to C at 56, random stages
+    conv3-conv5 of N/2, N and N nodes with C, 2C and 4C channels at 28, 14
+    and 7.  Regular: conv1 3x3/2 to C/2 at 112, random stages conv2-conv5
+    of N/2, N, N and N nodes with C, 2C, 4C and 8C channels at 56, 28, 14
+    and 7.  Both end in the classifier: a 1x1 conv to 1280, global pooling
+    and a 1000-way fc."""
+    n, c = RANDWIRE_N, 78 if variant == "A" else 109
     b = NetBuilder(f"randwire_{variant.lower()}", 224, 224, 3)
-    x = b.conv(b.input, c // 2, 3, 2, name="stem")
-    for si, (n, mult, stride) in enumerate([(32, 1, 2), (32, 2, 2), (32, 4, 2)]):
-        x = _randwire_stage(b, x, n=n, k=4, p=0.75, c=c * mult,
-                            stride=stride, seed=seed0 + si, tag=f"s{si}")
-    x = b.conv(x, 1280, 1, 1, name="head_conv")
-    x = b.global_pool(x)
-    x = b.fc(x, 1000, "fc")
+    x = b.conv(b.input, c // 2, 3, 2, name="conv1")
+    if variant == "A":
+        x = b.conv(x, c, 3, 2, name="conv2")
+        stages = [(3, n // 2, c), (4, n, 2 * c), (5, n, 4 * c)]
+    else:
+        stages = [(2, n // 2, c), (3, n, 2 * c), (4, n, 4 * c),
+                  (5, n, 8 * c)]
+    for (k, size, ch), seed in zip(stages, RANDWIRE_SEEDS[variant]):
+        x = _randwire_stage(b, x, size, ch, seed, f"conv{k}")
+    x = b.conv(x, 1280, 1, 1, name="classifier.conv")
+    x = b.global_pool(x, "classifier.pool")
+    x = b.fc(x, 1000, "classifier.fc")
     return b.done(x)
 
 

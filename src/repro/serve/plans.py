@@ -56,7 +56,7 @@ from repro.api.result import ExploreResult
 from repro.api.spec import ExploreSpec
 from repro.api.store import ResultStore, graph_fingerprint, spec_key
 from repro.api.strategies import run
-from repro.api.workloads import build_workload, workload_is_stable
+from repro.api.workloads import build_workload, workload_fingerprint
 from repro.obs.metrics import Histogram, render_metrics
 
 PROTOCOL_VERSION = 1
@@ -71,19 +71,17 @@ Searcher = Callable[[ExploreSpec], ExploreResult]
 
 def _validated_get(tier: Optional[ResultStore],
                    spec: ExploreSpec) -> Optional[ExploreResult]:
-    """A store hit, with the fingerprint revalidation :func:`repro.api.run`
-    applies: a non-stable workload URI (``file:`` — the file can change
-    under an unchanged URI) is re-resolved and its graph digest checked
-    before the artifact replays."""
+    """A store hit, with the fingerprint check :func:`repro.api.run`
+    applies: the artifact replays only if the workload URI still builds
+    the graph it was searched on."""
     if tier is None:
         return None
     cached = tier.get(spec)
     if cached is None:
         return None
-    if not workload_is_stable(spec.workload):
-        g = build_workload(spec.workload)
-        if cached.meta.get("graph_sha") not in (None, graph_fingerprint(g)):
-            return None
+    sha = workload_fingerprint(spec.workload)
+    if sha is not None and cached.meta.get("graph_sha") not in (None, sha):
+        return None
     return cached
 
 

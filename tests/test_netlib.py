@@ -1,5 +1,9 @@
 """Paper workload graphs: structure, statistics, schedulability."""
 
+import json
+from collections import Counter
+from pathlib import Path
+
 import pytest
 
 from repro.core import AcceleratorConfig, CachedEvaluator
@@ -46,6 +50,38 @@ def test_randwire_is_irregular_and_seeded():
     multi = [v for v in range(a1.n) if len(a1.in_edges(v)) > 2]
     assert multi
     assert b.n != a1.n or b.total_weight_bytes() != a1.total_weight_bytes()
+
+
+def test_randwire_a_is_the_published_small_regime():
+    """``randwire_a`` is node for node and edge for edge the graph of the
+    benchmark's RandWire-A configuration (arXiv:1904.01569 Table 2, small
+    regime)."""
+    path = (Path(__file__).resolve().parents[1] / "bench" / "configs" /
+            "randwire_a.json")
+    doc = json.loads(path.read_text())["graph"]
+    g = build("randwire_a")
+    assert (g.n, len(g.edges)) == (216, 313)
+    assert [(v.out_len, v.line_bytes, v.weight_bytes, v.macs,
+             bool(v.is_output)) for v in g.nodes] == \
+        [(n["out_len"], n["line_bytes"], n["weight_bytes"], n["macs"],
+          n["is_output"]) for n in doc["nodes"]]
+    assert [(e.src, e.dst, e.F, e.s, e.kind) for e in g.edges] == \
+        [(e["src"], e["dst"], e["F"], e["s"], e["kind"]) for e in doc["edges"]]
+
+
+def test_randwire_b_has_the_regular_regime_stages():
+    """Table 2, regular regime (C = 109): conv1 to C/2 at 112, then random
+    stages of N/2, N, N and N nodes with C, 2C, 4C and 8C channels at 56,
+    28, 14 and 7."""
+    g = build("randwire_b")
+    conv1 = next(v for v in g.nodes if v.name == "conv1")
+    assert (conv1.out_len, conv1.line_bytes) == (112, 112 * 54)
+    pointwise = Counter((v.name.split(".")[0], v.out_len, v.line_bytes)
+                        for v in g.nodes if v.name.endswith(".pw"))
+    assert pointwise == {("conv2", 56, 56 * 109): 16,
+                         ("conv3", 28, 28 * 218): 32,
+                         ("conv4", 14, 14 * 436): 32,
+                         ("conv5", 7, 7 * 872): 32}
 
 
 def test_single_netlib_table_no_drift():

@@ -1,6 +1,6 @@
 """Plan server (`repro.serve.plans`): tiered zoo→store→search resolution,
 in-flight request deduplication, warm evaluator reuse, fingerprint
-revalidation for unstable workloads, and the HTTP protocol + /stats schema.
+revalidation of every hit, and the HTTP protocol + /stats schema.
 """
 
 import json
@@ -91,6 +91,25 @@ def test_resolve_plan_revalidates_file_workloads(tmp_path):
     path.write_text(graph_to_json(chain_graph(8)[0]))   # file changed
     _, src3 = resolve_plan(spec, store=store)
     assert src3 == "search"
+
+
+@pytest.mark.parametrize("tier", ["store", "zoo"])
+def test_resolve_plan_refuses_an_artifact_of_another_graph(tmp_path, tier):
+    """A stable URI is fingerprint-checked too: an archived plan whose
+    graph_sha is not what the URI builds today is searched again."""
+    from repro.api import ExploreResult
+
+    spec = greedy_spec(workload="netlib:vgg16")
+    fresh, _ = resolve_plan(spec)
+    stale = ExploreResult.from_json(fresh.to_json())
+    stale.meta["graph_sha"] = "0" * 64
+    (tmp_path / "zoo").mkdir()
+    ResultStore(tmp_path / tier).put(spec, stale)
+    store = ResultStore(tmp_path / "store")
+    zoo = ResultStore(tmp_path / "zoo", read_only=True)
+    res, src = resolve_plan(spec, store=store, zoo=zoo)
+    assert src == "search"
+    assert res.meta["graph_sha"] == fresh.meta["graph_sha"]
 
 
 # ---------------------------------------------------------------------------

@@ -157,6 +157,27 @@ def test_file_workload_change_invalidates_store_hit(tmp_path):
     assert third.to_dict() == second.to_dict()
 
 
+def test_stored_artifact_of_another_graph_searches_again(tmp_path):
+    """A netlib: URI names a model, not the graph the program builds for it:
+    an artifact whose graph_sha differs from today's build (a plan of the
+    model as an earlier release built it) must search again, not replay."""
+    from repro.api import ExploreResult, GreedyOptions
+
+    store = ResultStore(tmp_path / "store")
+    spec = fixed_spec(workload="netlib:vgg16", strategy="greedy",
+                      options=GreedyOptions(eval_budget=1_000))
+    fresh = run(spec, store=store)
+    stale = ExploreResult.from_json(fresh.to_json())
+    stale.meta["graph_sha"] = "0" * 64
+    store.put(spec, stale)
+
+    again = run(spec, store=store)
+    assert again.meta["graph_sha"] == fresh.meta["graph_sha"]
+    assert again.to_dict() == fresh.to_dict()
+    # the new search overwrote the stale artifact, which now replays
+    assert store.get(spec).meta["graph_sha"] == fresh.meta["graph_sha"]
+
+
 # ---------------------------------------------------------------------------
 # hit / miss round-trip
 # ---------------------------------------------------------------------------
