@@ -17,10 +17,15 @@ Fitness = -(cost); cost is Formula 1 (partition-only) or Formula 2
 
 from __future__ import annotations
 
+import functools
+import gc
 import math
 import random
+import threading
+from contextlib import contextmanager
 from dataclasses import dataclass, field, replace
-from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
+from typing import (Callable, Dict, Iterator, List, Optional, Sequence, Set,
+                    Tuple)
 
 from .cost import (
     GLB_CANDIDATES,
@@ -390,6 +395,69 @@ def evaluate_genomes(g: Graph, genomes: Sequence[Genome], obj: Objective,
             genome.cost = obj.cost(plan, genome.acc)
 
 
+# ---------------------------------------------------------------------------
+# cyclic-collector pause
+# ---------------------------------------------------------------------------
+
+# The collector's switch is process-wide, so the count of pauses holding it
+# off is too.
+_gc_lock = threading.Lock()
+_gc_holders = 0
+_gc_resume = False   # the first holder found the collector on
+
+
+@contextmanager
+def gc_paused() -> Iterator[None]:
+    """Pause CPython's cyclic garbage collector for the enclosed block.
+
+    A search's genomes, groups, cache keys and costs form no reference
+    cycles, so reference counting frees all of its garbage; the collector's
+    full passes would only re-walk the live set, which the evaluator cache
+    and the population grow all search long, and find nothing.  Overlapping
+    or nested pauses (the plan server's search threads, ``two_step``'s inner
+    searches) keep the collector off until the last one leaves, and only a
+    collector that the first one found on is turned back on.  No collection
+    is forced on the way out: the collector's own thresholds decide.
+    """
+    global _gc_holders, _gc_resume
+    with _gc_lock:
+        if _gc_holders == 0:
+            _gc_resume = gc.isenabled()
+            gc.disable()
+        _gc_holders += 1
+    try:
+        yield
+    finally:
+        with _gc_lock:
+            _gc_holders -= 1
+            if _gc_holders == 0 and _gc_resume:
+                gc.enable()
+
+
+def _gc_collections() -> int:
+    """Collections the cyclic collector has run in this process."""
+    return sum(s["collections"] for s in gc.get_stats())
+
+
+def _collector_paused(search: Callable[..., SearchResult]
+                      ) -> Callable[..., SearchResult]:
+    """Run ``search`` inside :func:`gc_paused`, adding the collections run
+    meanwhile to the counter ``ga.gc_collections``: 0 unless something, such
+    as an explicit ``gc.collect``, collected inside the search."""
+
+    @functools.wraps(search)
+    def paused(*args, **kwargs) -> SearchResult:
+        with gc_paused():
+            before = _gc_collections()
+            try:
+                return search(*args, **kwargs)
+            finally:
+                obs.add("ga.gc_collections", _gc_collections() - before)
+
+    return paused
+
+
+@_collector_paused
 def run_ga(
     g: Graph,
     objective: Objective,
