@@ -68,11 +68,8 @@ def run_model(name: str, samples: int) -> Dict:
     specs.append(replace(base, strategy="ga",
                          options=GAOptions(population=POPULATION,
                                            seed_from=("dp", "greedy"))))
-    try:
-        results = {r.strategy: r for r in compare_cached(base, specs,
-                                                         graph=g, ev=ev)}
-    finally:
-        ev.close()  # release --eval-jobs worker pools between models
+    results = {r.strategy: r for r in compare_cached(base, specs,
+                                                     graph=g, ev=ev)}
 
     out: Dict[str, Dict] = {}
     greedy = results["greedy"]

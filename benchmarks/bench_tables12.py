@@ -53,11 +53,7 @@ def part_spec(g, acc, samples) -> ExploreSpec:
 
 def run_model(name: str, mode: str, samples: int) -> Dict:
     g = build(name)
-    ev = new_evaluator(g)
-    try:
-        return _run_model(g, ev, mode, samples)
-    finally:
-        ev.close()  # release --eval-jobs worker pools between models
+    return _run_model(g, new_evaluator(g), mode, samples)
 
 
 def _run_model(g, ev, mode: str, samples: int) -> Dict:

@@ -28,25 +28,21 @@ FULL = os.environ.get("REPRO_BENCH_FULL", "0") == "1"
 # process-wide sweep configuration, set once by benchmarks.run (or by tests)
 STORE: Optional[ResultStore] = None
 JOBS: int = 1
-EVAL_JOBS: int = 1
 EVAL_BACKEND: Optional[str] = None
 
 
 def configure(store_dir: Optional[str] = None, jobs: int = 1,
-              eval_jobs: int = 1,
               eval_backend: Optional[str] = None) -> None:
     """Point every subsequent run_cached/compare_cached at one store/pool.
 
-    ``jobs`` fans out whole strategies; ``eval_jobs``/``eval_backend``
-    parallelize cost evaluation *within* one strategy through the
-    evaluation engine (`repro.core.engine`: serial | process | vector |
-    jax) — results are identical either way, so both axes are safe under
-    the result store.
+    ``jobs`` fans out whole strategies; ``eval_backend`` picks how cost
+    queries *within* one strategy are evaluated (`repro.core.engine`:
+    serial | vector | jax) — results are identical either way, so both are
+    safe under the result store.
     """
-    global STORE, JOBS, EVAL_JOBS, EVAL_BACKEND
+    global STORE, JOBS, EVAL_BACKEND
     STORE = ResultStore(store_dir) if store_dir else None
     JOBS = max(1, jobs)
-    EVAL_JOBS = max(1, eval_jobs)
     EVAL_BACKEND = eval_backend
 
 
@@ -56,13 +52,13 @@ def new_evaluator(g, out_tile: int = 1):
     from repro.core.engine import make_executor
 
     return CachedEvaluator(g, out_tile=out_tile,
-                           executor=make_executor(EVAL_BACKEND, EVAL_JOBS))
+                           executor=make_executor(EVAL_BACKEND))
 
 
 def run_cached(spec: ExploreSpec, graph=None, ev=None) -> ExploreResult:
     """`repro.api.run` against the sweep-wide result store."""
     return api_run(spec, graph=graph, ev=ev, store=STORE,
-                   eval_jobs=EVAL_JOBS, eval_backend=EVAL_BACKEND)
+                   eval_backend=EVAL_BACKEND)
 
 
 def compare_cached(spec: ExploreSpec,
@@ -70,8 +66,7 @@ def compare_cached(spec: ExploreSpec,
                    graph=None, ev=None) -> List[ExploreResult]:
     """`repro.api.compare` with the sweep-wide store and process pool."""
     return api_compare(spec, strategies, graph=graph, ev=ev,
-                       jobs=JOBS, store=STORE,
-                       eval_jobs=EVAL_JOBS, eval_backend=EVAL_BACKEND)
+                       jobs=JOBS, store=STORE, eval_backend=EVAL_BACKEND)
 
 PARTITION_SAMPLES = 400_000 if FULL else 2_500
 COOPT_SAMPLES = 50_000 if FULL else 1_500

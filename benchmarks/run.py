@@ -3,7 +3,7 @@
 
     PYTHONPATH=src python -m benchmarks.run [--only fig3,fig11,...]
         [--store-dir runs/store] [--jobs N] [--no-store]
-        [--eval-jobs N] [--eval-backend serial|process|vector|jax]
+        [--eval-backend serial|vector|jax]
 
 Reduced sample budgets by default (REPRO_BENCH_FULL=1 for the paper's
 400k/50k budgets).  Emits `name,us_per_call,derived` CSV rows.
@@ -34,7 +34,6 @@ BENCHES = {
     "table3": "bench_table3",
     "workloads": "bench_workloads",
     "trace": "bench_trace",
-    "engine": "bench_engine",
     "serve": "bench_serve",
     "kernels": "bench_kernels",
     "roofline": "bench_roofline",
@@ -60,13 +59,9 @@ def main() -> None:
                     help="always search from scratch")
     ap.add_argument("--jobs", type=int, default=1,
                     help="worker processes for independent strategy runs")
-    ap.add_argument("--eval-jobs", type=int, default=1,
-                    help="evaluation-engine workers for batched cost "
-                         "queries within one strategy")
     ap.add_argument("--eval-backend", default=None,
-                    help="evaluation-engine executor: serial | process | "
-                         "vector | jax (default: process when "
-                         "--eval-jobs > 1, else serial)")
+                    help="evaluation-engine executor: serial | vector | "
+                         "jax (default: serial)")
     args = ap.parse_args()
     if args.eval_backend is not None:
         from repro.core.engine import backend_status
@@ -75,8 +70,7 @@ def main() -> None:
         if not ok:
             raise SystemExit(f"error: {why}")
     common.configure(store_dir=None if args.no_store else args.store_dir,
-                     jobs=args.jobs, eval_jobs=args.eval_jobs,
-                     eval_backend=args.eval_backend)
+                     jobs=args.jobs, eval_backend=args.eval_backend)
     names = list(BENCHES) if not args.only else args.only.split(",")
     unknown = [n for n in names if n not in BENCHES]
     if unknown:

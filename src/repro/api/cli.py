@@ -34,25 +34,24 @@ Subcommands:
 ``--store-dir`` (or ``$REPRO_STORE_DIR``) points both ``explore`` and
 ``compare`` at a spec-addressed result store: a spec that was already
 searched replays its archived result instantly instead of re-searching.
-``--eval-jobs N`` / ``--eval-backend`` parallelize cost evaluation *within*
-one strategy through the evaluation engine (``repro.core.engine``:
-``serial`` | ``process`` | ``vector`` | ``jax``); every backend returns
-bit-identical results, so they are pure runtime knobs (``jax`` batches
-whole GA generations onto the accelerator and needs the optional jax
-dependency).
+``--eval-backend`` picks how cost queries *within* one strategy are
+evaluated (``repro.core.engine``: ``serial`` | ``vector`` | ``jax``); every
+backend returns bit-identical results, so it is a pure runtime knob
+(``jax`` batches whole GA generations onto the accelerator and needs the
+optional jax dependency).
 
 ``explore --profile`` prints where the search spent its time (wall vs
 ``derive_schedule`` seconds) and the structure-cache counters (raw /
 canonical / disk hits vs misses).  ``--struct-cache-dir`` (or
 ``$REPRO_STRUCT_CACHE_DIR``) adds a disk-backed warm cache of canonical
-subgraph structures shared across runs and worker processes — gated like
-the result store: unset means no filesystem traffic.
+subgraph structures shared across runs and ``compare --jobs`` workers —
+gated like the result store: unset means no filesystem traffic.
 
 Examples::
 
     python -m repro explore --workload resnet50 --strategy ga \
         --metric energy --alpha 0.002 --hw-mode shared --budget 4000 \
-        --eval-jobs 4
+        --eval-backend vector
     python -m repro workloads ls --scheme tpu
     python -m repro explore --workload "tpu:gemma3-4b:0?tokens=4096" \
         --strategy ga --budget 2000
@@ -215,10 +214,9 @@ def _print_profile(res: ExploreResult) -> None:
     wall = prof.get("wall_s", 0.0)
     derive = prof.get("structure_derive_s", 0.0)
     pct = 100.0 * derive / wall if wall > 0 else 0.0
-    canon = "on" if prof.get("canonical") else "off"
     print(f"  profile: wall {wall:.2f}s, derive_schedule {derive:.2f}s "
           f"({pct:.0f}% of wall) over {prof.get('structure_misses', 0)} "
-          f"structure misses (canonical memo {canon})")
+          "structure misses")
     disk = ""
     if "structure_disk_writes" in prof:
         disk = (f", {prof.get('structure_disk_hits', 0)} disk hits / "
@@ -241,11 +239,11 @@ def cmd_explore(args: argparse.Namespace) -> int:
         rec = Recorder()
         with recording(rec):
             res = run(spec, store=store, eval_backend=args.eval_backend,
-                      eval_jobs=args.eval_jobs, profile=args.profile,
+                      profile=args.profile,
                       struct_cache_dir=args.struct_cache_dir)
     else:
         res = run(spec, store=store, eval_backend=args.eval_backend,
-                  eval_jobs=args.eval_jobs, profile=args.profile,
+                  profile=args.profile,
                   struct_cache_dir=args.struct_cache_dir)
     print(res.summary())
     if rec is not None:
@@ -285,7 +283,6 @@ def cmd_compare(args: argparse.Namespace) -> int:
     store = _store_from_args(args)
     results = compare(spec, names, jobs=args.jobs, store=store,
                       eval_backend=args.eval_backend,
-                      eval_jobs=args.eval_jobs,
                       struct_cache_dir=args.struct_cache_dir)
     ranked = sorted(results, key=lambda r: r.cost)
     _print_table([_result_row(r) for r in ranked])
@@ -424,7 +421,6 @@ def cmd_trace(args: argparse.Namespace) -> int:
         spec = _spec_from_args(args)
         store = _store_from_args(args)
         res = run(spec, store=store, eval_backend=args.eval_backend,
-                  eval_jobs=args.eval_jobs,
                   struct_cache_dir=args.struct_cache_dir)
         workload, strategy = spec.workload, spec.strategy
         seed, out_tile = spec.seed, spec.out_tile
@@ -527,8 +523,7 @@ def cmd_serve_plans(args: argparse.Namespace) -> int:
     zoo_dir = args.zoo_dir or os.environ.get("REPRO_ZOO_DIR")
     zoo = ResultStore(zoo_dir, read_only=True) if zoo_dir else None
     service = PlanService(store, zoo=zoo, workers=args.workers,
-                          eval_backend=args.eval_backend,
-                          eval_jobs=args.eval_jobs)
+                          eval_backend=args.eval_backend)
     server = PlanServer((args.host, args.port), service,
                         quiet=not args.verbose)
     if args.port_file:
@@ -686,19 +681,15 @@ def _add_spec_args(p: argparse.ArgumentParser) -> None:
                         "groups (full store key or a unique >= 8-char "
                         "prefix; repeatable; needs a store and strategy ga "
                         "— warm-start FULL-budget sweeps from reduced runs)")
-    p.add_argument("--eval-jobs", type=int, default=1,
-                   help="evaluation-engine workers for batched cost queries "
-                        "within one strategy (results are identical to "
-                        "serial evaluation)")
     p.add_argument("--eval-backend", default=None, metavar="NAME",
-                   help="evaluation-engine executor: serial | process | "
-                        "vector | jax (default: process when --eval-jobs "
-                        "> 1, else serial; jax needs the optional jax "
-                        "dependency and is checked up front)")
+                   help="evaluation-engine executor: serial | vector | jax "
+                        "(default: serial; results are identical under "
+                        "each; jax needs the optional jax dependency and "
+                        "is checked up front)")
     p.add_argument("--struct-cache-dir", metavar="DIR", default=None,
                    help="disk-backed warm cache for canonical subgraph "
-                        "structures, shared across runs and worker "
-                        "processes (default: $REPRO_STRUCT_CACHE_DIR if "
+                        "structures, shared across runs and compare "
+                        "--jobs workers (default: $REPRO_STRUCT_CACHE_DIR if "
                         "set; unset means no filesystem traffic)")
 
 
@@ -828,11 +819,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     psp.add_argument("--workers", type=int, default=2,
                      help="search worker threads (hits never queue behind "
                           "them)")
-    psp.add_argument("--eval-jobs", type=int, default=1,
-                     help="evaluation-engine workers per search")
     psp.add_argument("--eval-backend", default=None, metavar="NAME",
                      help="evaluation-engine executor per search (serial | "
-                          "process | vector | jax)")
+                          "vector | jax)")
     psp.add_argument("--port-file", metavar="PATH",
                      help="write the bound URL here once listening "
                           "(CI/scripts; pairs with --port 0)")

@@ -67,7 +67,6 @@ def active_store() -> Optional[ResultStore]:
 
 
 def _make_evaluator(g: Graph, out_tile: int, eval_backend: Optional[str],
-                    eval_jobs: int,
                     struct_cache_dir: Optional[str] = None) -> CachedEvaluator:
     """Build an evaluator whose executor matches the requested backend.
 
@@ -85,7 +84,7 @@ def _make_evaluator(g: Graph, out_tile: int, eval_backend: Optional[str],
 
         struct_cache = StructureCache(cache_dir)
     return CachedEvaluator(g, out_tile=out_tile,
-                           executor=make_executor(eval_backend, eval_jobs),
+                           executor=make_executor(eval_backend),
                            struct_cache=struct_cache)
 
 
@@ -106,7 +105,7 @@ def _counters_delta(before: Dict[str, object],
 def run(spec: ExploreSpec, graph: Optional[Graph] = None,
         ev: Optional[CachedEvaluator] = None,
         store: Optional[ResultStore] = None,
-        eval_backend: Optional[str] = None, eval_jobs: int = 1,
+        eval_backend: Optional[str] = None,
         profile: bool = False,
         struct_cache_dir: Optional[str] = None,
         **runtime) -> ExploreResult:
@@ -121,14 +120,13 @@ def run(spec: ExploreSpec, graph: Optional[Graph] = None,
     its address.  ``runtime`` carries non-serializable extras a strategy may
     accept (the GA takes ``init_groups``).
 
-    ``eval_backend``/``eval_jobs`` pick the evaluation-engine executor for
-    batched in-strategy cost queries (``serial`` | ``process`` | ``vector``
-    | ``jax``; ``eval_jobs > 1`` defaults the backend to ``process`` — see
-    :mod:`repro.core.engine`).  Every backend returns identical results, so
-    these are runtime knobs, deliberately *not* part of the spec (a stored
-    artifact addresses what was searched, not how it was scheduled).  They
-    apply when ``run`` builds the evaluator; a caller-provided ``ev`` keeps
-    its own executor.
+    ``eval_backend`` picks the evaluation-engine executor for batched
+    in-strategy cost queries (``serial`` | ``vector`` | ``jax``, default
+    ``serial`` — see :mod:`repro.core.engine`).  Every backend returns
+    identical results, so it is a runtime knob, deliberately *not* part of
+    the spec (a stored artifact addresses what was searched, not how it was
+    evaluated).  It applies when ``run`` builds the evaluator; a
+    caller-provided ``ev`` keeps its own executor.
 
     ``result.evaluations`` is set here, uniformly for every strategy, to the
     number of *distinct* (subgraph, hardware-point) cost-model queries the
@@ -164,9 +162,8 @@ def run(spec: ExploreSpec, graph: Optional[Graph] = None,
     else:
         with obs.span("resolve-workload", workload=spec.workload):
             g = build_workload(spec.workload)
-    created_ev = ev is None
-    if created_ev:
-        ev = _make_evaluator(g, spec.out_tile, eval_backend, eval_jobs,
+    if ev is None:
+        ev = _make_evaluator(g, spec.out_tile, eval_backend,
                              struct_cache_dir)
     entry = get_strategy(spec.strategy)
     options = spec.options
@@ -197,8 +194,6 @@ def run(spec: ExploreSpec, graph: Optional[Graph] = None,
             result = entry.fn(spec, options, g, ev, **runtime)
     finally:
         _ACTIVE_STORE.reset(token)
-        if created_ev:
-            ev.close()  # release executor pools; the cache dies with ev
     result.evaluations = len(touched)
     result.spec = spec
     result.meta.setdefault("graph", g.name)
@@ -242,7 +237,6 @@ def compare(spec: ExploreSpec,
             jobs: int = 1,
             store: Optional[ResultStore] = None,
             eval_backend: Optional[str] = None,
-            eval_jobs: int = 1,
             struct_cache_dir: Optional[str] = None) -> List[ExploreResult]:
     """Run several strategies on one spec, sharing a single evaluator cache.
 
@@ -262,18 +256,18 @@ def compare(spec: ExploreSpec,
     anything importable from the worker) are supported; with the ``fork``
     start method (Linux default) runtime-registered strategies work too.
     When jax has been imported, workers start via ``forkserver`` instead
-    (see :func:`repro.core.engine.pool_mp_context`) so no process forks a
-    multithreaded jax runtime.
+    (see :func:`pool_mp_context`) so no process forks a multithreaded jax
+    runtime.
 
     ``store`` serves store hits in the parent without spawning a worker and
     persists every miss, so an interrupted comparison resumes where it
     stopped.
 
-    ``eval_backend``/``eval_jobs`` select the evaluation-engine executor for
+    ``eval_backend`` selects the evaluation-engine executor for
     *within-strategy* batches (a different axis than ``jobs``, which fans
-    out whole strategies).  They configure the shared evaluator on the
+    out whole strategies).  It configures the shared evaluator on the
     serial path; with ``jobs > 1`` each worker keeps the default serial
-    executor — nesting process pools inside workers oversubscribes cores.
+    executor, so no worker process touches the device.
 
     ``struct_cache_dir`` (default ``$REPRO_STRUCT_CACHE_DIR``) attaches the
     disk-backed canonical structure cache; with ``jobs > 1`` each worker
@@ -284,18 +278,12 @@ def compare(spec: ExploreSpec,
     """
     subs = _resolve_compare_specs(spec, strategies)
     g = graph if graph is not None else build_workload(spec.workload)
-    created_ev = ev is None
-    if created_ev:
-        ev = _make_evaluator(g, spec.out_tile, eval_backend, eval_jobs,
+    if ev is None:
+        ev = _make_evaluator(g, spec.out_tile, eval_backend,
                              struct_cache_dir)
-    try:
-        if jobs and jobs > 1 and len(subs) > 1:
-            return _compare_parallel(subs, g, ev, jobs, store,
-                                     struct_cache_dir)
-        return [run(sub, graph=g, ev=ev, store=store) for sub in subs]
-    finally:
-        if created_ev:
-            ev.close()
+    if jobs and jobs > 1 and len(subs) > 1:
+        return _compare_parallel(subs, g, ev, jobs, store, struct_cache_dir)
+    return [run(sub, graph=g, ev=ev, store=store) for sub in subs]
 
 
 def _compare_worker(
@@ -311,10 +299,30 @@ def _compare_worker(
     """
     spec = ExploreSpec.from_json(spec_json)
     g = graph if graph is not None else build_workload(spec.workload)
-    ev = _make_evaluator(g, spec.out_tile, None, 1, struct_cache_dir)
+    ev = _make_evaluator(g, spec.out_tile, None, struct_cache_dir)
     worker_store = ResultStore(store_dir) if store_dir else None
     result = run(spec, graph=g, ev=ev, store=worker_store)
     return result, ev.cache_snapshot(), ev.structure_snapshot()
+
+
+def pool_mp_context():
+    """The multiprocessing context of parallel ``compare``'s worker pool.
+
+    Default start method (fork on Linux) while the process is jax-free:
+    spawn/forkserver would re-import ``__main__`` and break REPL/stdin
+    callers, and the workers only search on the host.  Once jax is imported
+    the process is multithreaded and forking it both trips jax's at-fork
+    ``RuntimeWarning`` and genuinely risks deadlock, so the pool switches
+    to ``forkserver``: workers fork from a clean, jax-free server process
+    instead of this one.  Searches are deterministic, so results are
+    identical under either context.
+    """
+    import multiprocessing as mp
+    import sys
+
+    if "jax" in sys.modules and "forkserver" in mp.get_all_start_methods():
+        return mp.get_context("forkserver")
+    return mp.get_context()
 
 
 def _compare_parallel(subs: List[ExploreSpec], g: Graph,
@@ -322,8 +330,6 @@ def _compare_parallel(subs: List[ExploreSpec], g: Graph,
                       store: Optional[ResultStore],
                       struct_cache_dir: Optional[str] = None,
                       ) -> List[ExploreResult]:
-    from repro.core.engine import pool_mp_context
-
     results: List[Optional[ExploreResult]] = [None] * len(subs)
     pending = list(range(len(subs)))
     if store is not None:

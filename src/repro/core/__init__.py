@@ -23,7 +23,6 @@ from .cost import (
 )
 from .engine import (
     Executor,
-    ProcessExecutor,
     SerialExecutor,
     VectorExecutor,
     make_executor,

@@ -24,7 +24,6 @@ ARM-memory-compiler-style sqrt model) + MAC energy.
 from __future__ import annotations
 
 import math
-import os
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field, replace as dataclass_replace
@@ -394,8 +393,8 @@ class PlanCost:
 #       access traffic.  Depends only on the node set (and out_tile).
 #   finish_cost(structure, acc)            — the cheap, hardware-dependent
 #       half: feasibility vs the buffer capacities, single-layer weight
-#       streaming, multi-core weight sharing.  Pure elementwise arithmetic,
-#       so a whole batch vectorizes (engine.VectorExecutor).
+#       streaming, multi-core weight sharing.  Pure elementwise arithmetic;
+#       finish_arrays is the same arithmetic over a whole batch of arrays.
 #
 # evaluate_subgraph == finish_cost(compute_structure(...), acc) exactly.
 
@@ -464,7 +463,8 @@ def finish_cost(st: SubgraphStructure, acc: AcceleratorConfig) -> SubgraphCost:
     """Hardware-dependent half: capacities, streaming, weight sharing.
 
     Pure arithmetic in ``st``'s fields and ``acc``'s capacities — the
-    branch structure here is what ``engine.VectorExecutor`` vectorizes.
+    branch structure here is what :func:`finish_arrays` computes over a
+    batch.
     """
     sc = SubgraphCost(nodes=st.nodes, macs=st.macs,
                       weight_resident=st.weight_total,
@@ -512,6 +512,45 @@ def finish_cost(st: SubgraphStructure, acc: AcceleratorConfig) -> SubgraphCost:
     return sc
 
 
+def finish_arrays(xp, fp, w_total, single, glb, wbuf, shared, share):
+    """:func:`finish_cost` over a batch of equal-length arrays.
+
+    ``xp`` is the array namespace: ``numpy`` for the ``vector`` executor,
+    ``jax.numpy`` inside the ``jax`` executor's jitted kernel
+    (:mod:`repro.kernels.finish_batch`).  Inputs are int64 values and bool
+    masks, one lane per query, every lane inside the engine's
+    scalar-fallback guards (:func:`repro.core.engine.needs_scalar_fallback`)
+    — a failed schedule never reaches here.  Returns ``(wr, n_blocks, ema_w,
+    fp_out, noc, infeasible_buf, w_overflow, stream, feasible)``,
+    index-aligned with the inputs and bit-identical, lane by lane, to
+    :func:`finish_cost`.  Same branch structure: buffer overflow splits into
+    infeasible (multi-node) vs streaming (single-node); separate-buffer
+    weight overflow only ever invalidates multi-node subgraphs.
+    """
+    # the guards keep 0 <= w_total < 2**31 and 1 <= share < 2**31, so the
+    # quotient is exact in int32; XLA:TPU emulates 64-bit integer division,
+    # and that emulation took most of the device kernel's compile time
+    wr = (w_total.astype(xp.int32)
+          // share.astype(xp.int32)).astype(xp.int64)
+    # mirrors _stream_single_layer: math.ceil of a float64 true division
+    n_blocks = xp.maximum(
+        xp.ceil(fp / xp.maximum(glb, 1)).astype(xp.int64), 1)
+    wbuf_cap = xp.where(shared, glb, wbuf)
+    overflow = xp.where(shared, fp + wr > glb, fp > glb)
+    infeasible_buf = overflow & ~single
+    stream = overflow & single
+    ema_w = xp.where(stream, wr * n_blocks, w_total)
+    fp_out = xp.where(stream, xp.minimum(fp, glb), fp)
+    w_overflow = ~shared & ~single & ~infeasible_buf & (wr > wbuf_cap)
+    feasible = ~(infeasible_buf | w_overflow)
+    # §5.4.2 NoC charge: every DRAM-loaded weight byte crosses the fabric to
+    # the share - 1 peer cores; the guards bound share * w_total below
+    # 2**31, so the product stays int64-safe even for a streamed ema_w
+    noc = (share - 1) * ema_w
+    return (wr, n_blocks, ema_w, fp_out, noc, infeasible_buf, w_overflow,
+            stream, feasible)
+
+
 def evaluate_subgraph(
     g: Graph,
     nodes: Set[int],
@@ -531,11 +570,6 @@ def _stream_single_layer(sc: SubgraphCost, glb_cap: int) -> None:
     sc.ema_w = sc.weight_resident * n_blocks
     sc.footprint = min(sc.footprint, glb_cap)
     sc.reason = f"{STREAM_REASON} in {n_blocks} blocks"
-
-
-# canonical memoization default: on everywhere, disabled only for honest
-# before/after measurement (REPRO_STRUCT_CANON=0)
-_CANON_ENV = "REPRO_STRUCT_CANON"
 
 
 def canonical_structure_key(g: Graph, nodes: Set[int],
@@ -607,8 +641,7 @@ class CostKernel:
     ``cost(nodes, acc)`` is a deterministic, side-effect-free function of
     its arguments; the only state here is memoization of
     :func:`compute_structure` (itself pure), shared by every executor
-    backend.  Worker processes hold their own ``CostKernel`` and stay warm
-    across batches.
+    backend.
 
     The memo has up to three tiers, consulted in order:
 
@@ -623,19 +656,12 @@ class CostKernel:
     3. **disk** (optional) — a :class:`~repro.core.structcache.
        StructureCache` warming the canonical tier across processes and
        runs, gated like the result store.
-
-    Canonical memoization is on by default; set ``REPRO_STRUCT_CANON=0``
-    (or ``canonical=False``) to disable it for before/after measurement.
     """
 
     def __init__(self, g: Graph, out_tile: int = 1,
-                 canonical: Optional[bool] = None,
                  struct_cache: Optional[Any] = None) -> None:
         self.g = g
         self.out_tile = out_tile
-        if canonical is None:
-            canonical = os.environ.get(_CANON_ENV, "1") != "0"
-        self.canonical = bool(canonical)
         self.struct_cache = struct_cache
         self._structures: Dict[frozenset, SubgraphStructure] = {}
         self._canon: Dict[Tuple, SubgraphStructure] = {}
@@ -652,27 +678,25 @@ class CostKernel:
         if st is not None:
             self.structure_raw_hits += 1
             return st
-        key: Optional[Tuple] = None
-        if self.canonical:
-            key = canonical_structure_key(self.g, nodes, self.out_tile)
-            st = self._canon.get(key)
-            if st is None and self.struct_cache is not None:
-                st = self.struct_cache.get(key)
-                if st is not None:
-                    self.structure_disk_hits += 1
-                    self._canon[key] = st
-            elif st is not None:
-                self.structure_canon_hits += 1
+        key = canonical_structure_key(self.g, nodes, self.out_tile)
+        st = self._canon.get(key)
+        if st is None and self.struct_cache is not None:
+            st = self.struct_cache.get(key)
             if st is not None:
-                st = dataclass_replace(st, nodes=tuple(sorted(nodes)))
-                self._structures[nodes] = st
-                return st
+                self.structure_disk_hits += 1
+                self._canon[key] = st
+        elif st is not None:
+            self.structure_canon_hits += 1
+        if st is not None:
+            st = dataclass_replace(st, nodes=tuple(sorted(nodes)))
+            self._structures[nodes] = st
+            return st
         t0 = time.perf_counter()
         st = compute_structure(self.g, set(nodes), out_tile=self.out_tile)
         self.structure_time_s += time.perf_counter() - t0
         self.structure_misses += 1
         self._structures[nodes] = st
-        if key is not None and st.sched_error is None:
+        if st.sched_error is None:
             self._canon[key] = st
             if self.struct_cache is not None:
                 self.struct_cache.put(key, st)
@@ -728,19 +752,18 @@ class CachedEvaluator:
 
     The evaluator is cache + counters only; *how* misses are computed is the
     ``executor``'s job (:mod:`repro.core.engine`): ``serial`` evaluates them
-    inline through the pure :class:`CostKernel`, ``process`` shards a batch
-    over worker processes, ``vector`` batches the hardware-dependent
-    arithmetic through NumPy.  Every backend returns identical costs (the
+    inline through the pure :class:`CostKernel`; ``vector`` and ``jax`` batch
+    the hardware-dependent arithmetic through NumPy or on the jax device.
+    Every backend returns identical costs (the
     kernel is deterministic), so search results do not depend on the backend.
     """
 
     def __init__(self, g: Graph, out_tile: int = 1,
                  executor: Optional["Executor"] = None,
-                 canonical: Optional[bool] = None,
                  struct_cache: Optional[Any] = None) -> None:
         self.g = g
         self.out_tile = out_tile
-        self.kernel = CostKernel(g, out_tile=out_tile, canonical=canonical,
+        self.kernel = CostKernel(g, out_tile=out_tile,
                                  struct_cache=struct_cache)
         self._executor = executor
         self._cache: Dict[Tuple, SubgraphCost] = {}
@@ -755,11 +778,6 @@ class CachedEvaluator:
             from .engine import SerialExecutor  # deferred: engine imports us
             self._executor = SerialExecutor()
         return self._executor
-
-    def close(self) -> None:
-        """Release executor resources (worker pools); the cache survives."""
-        if self._executor is not None:
-            self._executor.close()
 
     def _key(self, nodes: frozenset, acc: AcceleratorConfig) -> Tuple:
         return (nodes, acc.glb_bytes, acc.wbuf_bytes, acc.shared,
@@ -784,11 +802,11 @@ class CachedEvaluator:
         """Evaluate a batch of (nodes, acc) queries through the executor.
 
         Cache hits are served directly; distinct misses are submitted to the
-        executor as one batch (where ``process``/``vector`` backends get
-        their parallelism) and adopted into the cache on return.  Results
-        come back in query order and are identical to issuing
-        :meth:`subgraph` serially — batching changes the execution schedule,
-        never the values or the distinct-query accounting.
+        executor as one batch (one device call under ``jax``) and adopted
+        into the cache on return.  Results come back in query order and are
+        identical to issuing :meth:`subgraph` serially — batching changes the
+        execution schedule, never the values or the distinct-query
+        accounting.
         """
         results: List[Optional[SubgraphCost]] = [None] * len(queries)
         miss_keys: List[Tuple] = []
@@ -877,9 +895,10 @@ class CachedEvaluator:
 
     def counters(self) -> Dict[str, Any]:
         """One flat dict of every cache/structure counter (the ``--profile``
-        surface).  Structure counters are process-local: misses evaluated by
-        a worker backend show up here only as adopted canonical entries
-        (``structure_merged``), not as local derivations."""
+        surface).  Structure counters are process-local: structures derived
+        by parallel ``compare``'s workers show up here only as adopted
+        canonical entries (``structure_merged``), not as local
+        derivations."""
         k = self.kernel
         out: Dict[str, Any] = {
             "lookups": self.lookups,
@@ -891,7 +910,6 @@ class CachedEvaluator:
             "structure_misses": k.structure_misses,
             "structure_merged": k.structure_merged,
             "structure_derive_s": k.structure_time_s,
-            "canonical": k.canonical,
         }
         if k.struct_cache is not None:
             out["structure_disk_writes"] = k.struct_cache.writes

@@ -3,8 +3,9 @@
 The accelerator-resident half of the ``jax`` executor backend
 (:class:`repro.core.engine.JaxExecutor`): a whole GA generation's distinct
 ``(structure, AcceleratorConfig)`` queries arrive as struct-of-arrays int64
-buffers and the capacity / streaming / weight-sharing arithmetic of
-:func:`repro.core.cost.finish_cost` runs as one device call.
+buffers and :func:`repro.core.cost.finish_arrays` — the array form of
+:func:`repro.core.cost.finish_cost` that the ``vector`` backend runs over
+NumPy — runs over ``jax.numpy`` as one device call.
 
 Bitwise parity with the scalar kernel is the contract (the engine's guards
 keep every lane below ``2**53`` / int64-product-safe, see
@@ -40,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.cost import finish_arrays
 from repro.obs import recorder as obs
 
 # fixed in-checkout cache path: the directory is part of the cache key, so
@@ -73,35 +75,9 @@ def compile_cache_dir() -> Optional[str]:
 # trace reduction finds it by that name: renaming it blanks its trace metrics
 @jax.jit
 def _finish_jnp(fp, w_total, single, glb, wbuf, shared, share):
-    """Whole-batch ``finish_cost`` arithmetic as one jitted jnp expression.
-
-    Mirrors ``finish_cost``'s branch structure: buffer overflow splits into
-    infeasible (multi-node) vs streaming (single-node); separate-buffer
-    weight overflow only ever invalidates multi-node subgraphs.
-    """
-    # the guards keep 0 <= w_total < 2**31 and 1 <= share < 2**31, so the
-    # quotient is exact in int32; XLA:TPU emulates 64-bit integer division,
-    # and that emulation took most of the kernel's compile time
-    wr = (w_total.astype(jnp.int32)
-          // share.astype(jnp.int32)).astype(jnp.int64)
-    # mirrors _stream_single_layer: math.ceil of a float64 true division
-    n_blocks = jnp.maximum(
-        jnp.ceil(fp / jnp.maximum(glb, 1)).astype(jnp.int64), 1)
-    wbuf_cap = jnp.where(shared, glb, wbuf)
-    overflow = jnp.where(shared, fp + wr > glb, fp > glb)
-    infeasible_buf = overflow & ~single
-    stream = overflow & single
-    ema_w = jnp.where(stream, wr * n_blocks, w_total)
-    fp_out = jnp.where(stream, jnp.minimum(fp, glb), fp)
-    w_overflow = ~shared & ~single & ~infeasible_buf & (wr > wbuf_cap)
-    feasible = ~(infeasible_buf | w_overflow)
-    # §5.4.2 NoC charge, mirroring finish_cost: every DRAM-loaded weight
-    # byte crosses the fabric to the share - 1 peer cores; the engine's
-    # guards bound share * w_total below 2**31, so the product stays
-    # int64-safe even for a streamed ema_w
-    noc = (share - 1) * ema_w
-    return (wr, n_blocks, ema_w, fp_out, noc, infeasible_buf, w_overflow,
-            stream, feasible)
+    """Whole-batch ``finish_cost`` arithmetic as one jitted jnp expression:
+    :func:`repro.core.cost.finish_arrays` over ``jax.numpy``."""
+    return finish_arrays(jnp, fp, w_total, single, glb, wbuf, shared, share)
 
 
 def _pad(arr: np.ndarray, m: int, fill) -> np.ndarray:
@@ -120,7 +96,7 @@ def finish_cost_batch(fp, w_total, single, glb, wbuf, shared,
     masks); every lane must already satisfy the engine's scalar-fallback
     guards.  Returns ``(wr, n_blocks, ema_w, fp_out, noc, infeasible_buf,
     w_overflow, stream, feasible)`` as NumPy arrays, bit-identical to the
-    scalar kernel and to :class:`repro.core.engine.VectorExecutor`.
+    scalar kernel: :func:`repro.core.cost.finish_arrays` on the device.
 
     A call records three host spans, each with ``lanes`` and ``padded``:
     ``executor.put`` (padding, and enqueueing the 7 inputs' transfer),

@@ -165,14 +165,12 @@ class PlanService:
                  zoo: Optional[ResultStore] = None,
                  workers: int = 2,
                  eval_backend: Optional[str] = None,
-                 eval_jobs: int = 1,
                  max_warm_evaluators: int = 8,
                  lock_timeout: Optional[float] = None) -> None:
         self.store = store
         self.zoo = zoo
         self.workers = max(1, workers)
         self.eval_backend = eval_backend
-        self.eval_jobs = eval_jobs
         self.max_warm_evaluators = max(1, max_warm_evaluators)
         self.lock_timeout = lock_timeout
         self.started = time.time()
@@ -269,8 +267,7 @@ class PlanService:
             if warm is None:
                 warm = _WarmEvaluator(CachedEvaluator(
                     g, out_tile=out_tile,
-                    executor=make_executor(self.eval_backend,
-                                           self.eval_jobs)))
+                    executor=make_executor(self.eval_backend)))
                 self._evaluators[key] = warm
             self._evaluators.move_to_end(key)
             # LRU-evict cold evaluators (skip any mid-search: its searcher
@@ -278,7 +275,7 @@ class PlanService:
             while len(self._evaluators) > self.max_warm_evaluators:
                 for k in list(self._evaluators):
                     if k != key and not self._evaluators[k].lock.locked():
-                        self._evaluators.pop(k).ev.close()
+                        self._evaluators.pop(k)
                         break
                 else:
                     break
@@ -395,10 +392,7 @@ class PlanService:
         self._closed = True
         self._pool.shutdown(wait=True)
         with self._lock:
-            evs, self._evaluators = list(self._evaluators.values()), \
-                OrderedDict()
-        for warm in evs:
-            warm.ev.close()
+            self._evaluators.clear()
 
 
 # ---------------------------------------------------------------------------

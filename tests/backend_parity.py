@@ -1,7 +1,7 @@
 """Differential-parity harness: the reusable fixture layer behind the
 cross-backend acceptance gate.
 
-Every executor backend (``serial`` / ``process`` / ``vector`` / ``jax``)
+Every executor backend (``serial`` / ``vector`` / ``jax``)
 must return *bitwise-identical* :class:`SubgraphCost`s and whole-strategy
 ``ExploreResult``s.  This module is importable (not collected — no
 ``test_`` prefix) and supplies:
@@ -38,10 +38,9 @@ from repro.core.partition import random_partition
 KB = 1 << 10
 GOLDEN_DIR = Path(__file__).resolve().parent / "golden"
 
-# (backend, eval_jobs) rows every invariance test parametrizes over; the
-# serial row is the reference most tests compare *against*, so it is
-# excluded by default
-BACKEND_MATRIX = (("serial", 1), ("process", 2), ("vector", 1), ("jax", 1))
+# backends every invariance test parametrizes over; serial is the
+# reference most tests compare *against*, so it is excluded by default
+BACKEND_MATRIX = BACKENDS
 
 # one golden workload per URI scheme (the same four the golden-artifact
 # suite pins)
@@ -58,7 +57,7 @@ _COST_FIELDS = tuple(f.name for f in dataclass_fields(SubgraphCost))
 
 
 def backend_params(include_serial=False):
-    """``pytest.param(backend, jobs)`` rows over :data:`BACKEND_MATRIX`.
+    """``pytest.param(backend)`` rows over :data:`BACKEND_MATRIX`.
 
     Unavailable backends come back marked ``skip`` with the engine's
     why-not message (e.g. the jax import failure), so a missing optional
@@ -66,18 +65,18 @@ def backend_params(include_serial=False):
     shrinking coverage.
     """
     params = []
-    for backend, jobs in BACKEND_MATRIX:
+    for backend in BACKEND_MATRIX:
         if backend == "serial" and not include_serial:
             continue
         ok, why = backend_status(backend)
         marks = [] if ok else [pytest.mark.skip(reason=why)]
-        params.append(pytest.param(backend, jobs, id=backend, marks=marks))
+        params.append(pytest.param(backend, id=backend, marks=marks))
     return params
 
 
 def available_backends(include_serial=True):
-    """The (backend, jobs) rows that resolve right now, for plain loops."""
-    return [(b, j) for b, j in BACKEND_MATRIX
+    """The backends that resolve right now, for plain loops."""
+    return [b for b in BACKEND_MATRIX
             if (include_serial or b != "serial") and backend_status(b)[0]]
 
 
@@ -156,14 +155,10 @@ def assert_costs_equal(got, want, context=""):
         f"SubgraphCost mismatch {context}: " + "; ".join(diffs))
 
 
-def assert_backend_parity(g, queries, backend, jobs=1):
+def assert_backend_parity(g, queries, backend):
     """One backend's batch answers equal the scalar serial reference."""
-    ex = make_executor(backend, jobs)
     reference = CostKernel(g)
-    try:
-        got = ex.evaluate(CostKernel(g), queries)
-    finally:
-        ex.close()
+    got = make_executor(backend).evaluate(CostKernel(g), queries)
     assert len(got) == len(queries)
     for (nodes, acc), cost in zip(queries, got):
         assert_costs_equal(
@@ -182,7 +177,7 @@ def strategy_results(spec, graph, backends=None):
     from repro.api import run
 
     out = {}
-    for backend, jobs in (backends or available_backends()):
-        res = run(spec, graph=graph, eval_backend=backend, eval_jobs=jobs)
+    for backend in (backends or available_backends()):
+        res = run(spec, graph=graph, eval_backend=backend)
         out[backend] = res.to_json()
     return out

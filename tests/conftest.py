@@ -13,9 +13,9 @@ from repro.core import Graph
 def pytest_configure(config):
     # Regression guard for the jax-after-fork class of bugs: CPython warns
     # (and jax can deadlock) when a process pool forks a process that
-    # already imported the multithreaded jax runtime.  The engine's pools
-    # switch to the forkserver start method once jax is loaded
-    # (repro.core.engine.pool_mp_context), so any reappearance of this
+    # already imported the multithreaded jax runtime.  Parallel compare's
+    # pool switches to the forkserver start method once jax is loaded
+    # (repro.api.strategies.pool_mp_context), so any reappearance of this
     # warning is a real bug — fail loudly instead of scrolling by.
     config.addinivalue_line(
         "filterwarnings",

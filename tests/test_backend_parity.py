@@ -40,6 +40,7 @@ from repro.api import (
 from repro.core import (
     AcceleratorConfig,
     CachedEvaluator,
+    CostKernel,
     HWSpace,
     Objective,
     compute_structure,
@@ -48,6 +49,8 @@ from repro.core import (
     make_executor,
     random_partition,
 )
+from repro.core.engine import needs_scalar_fallback
+from repro.obs import recorder as obs
 
 KB = 1 << 10
 
@@ -56,22 +59,22 @@ KB = 1 << 10
 # corpus sweeps: SubgraphCost equality field-by-field
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend,jobs", backend_params())
-def test_scheme_corpus_parity(backend, jobs):
+@pytest.mark.parametrize("backend", backend_params())
+def test_scheme_corpus_parity(backend):
     """Golden workloads of all four URI schemes, adversarial HW points."""
     for label, g, queries in scheme_corpus():
-        assert_backend_parity(g, queries, backend, jobs)
+        assert_backend_parity(g, queries, backend)
 
 
-@pytest.mark.parametrize("backend,jobs", backend_params())
-def test_fuzz_corpus_parity(backend, jobs):
+@pytest.mark.parametrize("backend", backend_params())
+def test_fuzz_corpus_parity(backend):
     """Seeded synthetic fuzz graphs of every generator kind."""
     for label, g, queries in fuzz_corpus():
-        assert_backend_parity(g, queries, backend, jobs)
+        assert_backend_parity(g, queries, backend)
 
 
 def test_jax_executor_handles_empty_and_all_fallback_batches():
-    if ("jax", 1) not in available_backends():
+    if "jax" not in available_backends():
         pytest.skip("jax not installed")
     from repro.core.cost import CostKernel
 
@@ -85,6 +88,73 @@ def test_jax_executor_handles_empty_and_all_fallback_batches():
     want = [CostKernel(g).cost(n, a) for n, a in queries]
     for a, b in zip(got, want):
         assert_costs_equal(a, b, "all-fallback batch")
+
+
+def _lane_mix(n_lanes):
+    """``(graph, queries, n_fallback)``: ``n_lanes`` distinct queries that
+    the array backends batch, with distinct scalar-fallback queries
+    interleaved (one after every fourth batched lane, at least one)."""
+    g = build_workload("synthetic:layered:24?seed=7")
+    kernel = CostKernel(g)
+    rng = random.Random(n_lanes)
+    spaces = (HWSpace(mode="separate"), HWSpace(mode="shared"))
+    sets = []
+    for _ in range(4):
+        for s in random_partition(g, rng, mean_size=rng.uniform(1.5, 6.0)):
+            if frozenset(s) not in sets:
+                sets.append(frozenset(s))
+    batched = []
+    seen = set()
+    while len(batched) < n_lanes:
+        # every finish_cost branch: streaming and overflow at starved
+        # buffers, weight overflow, multi-core sharing, roomy points
+        acc = rng.choice([
+            spaces[len(batched) % 2].sample(rng),
+            AcceleratorConfig(glb_bytes=2 * KB, wbuf_bytes=2 * KB),
+            AcceleratorConfig(glb_bytes=512 * KB, wbuf_bytes=1 * KB),
+            AcceleratorConfig(glb_bytes=128 * KB, wbuf_bytes=144 * KB,
+                              weight_share_cores=rng.choice((2, 4))),
+        ])
+        q = (rng.choice(sets), acc)
+        if q not in seen and not needs_scalar_fallback(
+                kernel.structure(q[0]), acc):
+            seen.add(q)
+            batched.append(q)
+    # capacities from 2**53 up take the scalar path (float64 exactness)
+    n_fallback = max(1, n_lanes // 4)
+    fallback = [(sets[j % len(sets)],
+                 AcceleratorConfig(glb_bytes=(1 << 53) + j,
+                                   wbuf_bytes=144 * KB))
+                for j in range(n_fallback)]
+    assert all(needs_scalar_fallback(kernel.structure(s), a)
+               for s, a in fallback)
+    queries = []
+    for i, q in enumerate(batched):
+        queries.append(q)
+        if i % 4 == 0 and fallback:
+            queries.append(fallback.pop())
+    return g, queries + fallback, n_fallback
+
+
+@pytest.mark.parametrize("n_lanes", [1, 2, 3, 255, 256, 257])
+def test_padding_boundary_parity(n_lanes):
+    """The device kernel pads its lanes to the next power of two: at
+    ``2**k`` and ``2**k + 1`` batched lanes, every real lane of the array
+    backends still equals ``serial`` field for field, next to the
+    scalar-fallback lanes of the same batch."""
+    g, queries, n_fallback = _lane_mix(n_lanes)
+    reference = CostKernel(g)
+    want = [reference.cost(nodes, acc) for nodes, acc in queries]
+    for backend in available_backends(include_serial=False):
+        rec = obs.Recorder()
+        with obs.recording(rec):
+            got = make_executor(backend).evaluate(CostKernel(g), queries)
+        assert rec.counters["engine.scalar_fallback"] == n_fallback
+        if backend == "jax":
+            assert rec.counters["engine.device_lanes"] == n_lanes
+        assert len(got) == len(want)
+        for i, (a, b) in enumerate(zip(got, want)):
+            assert_costs_equal(a, b, f"[{backend}] lane {i} of {n_lanes}")
 
 
 # ---------------------------------------------------------------------------
@@ -156,14 +226,11 @@ def _check_triple(kind, n, gseed, pseed):
             assert evaluate_subgraph(g, set(s), acc) == \
                 finish_cost(compute_structure(g, set(s)), acc)
     serial_plans = [CachedEvaluator(g).plan(groups, acc) for acc in accs]
-    for backend, jobs in available_backends(include_serial=False):
-        assert_backend_parity(g, queries, backend, jobs)
+    for backend in available_backends(include_serial=False):
+        assert_backend_parity(g, queries, backend)
         # plan-level: the batched plan path reproduces the serial plans
-        ev = CachedEvaluator(g, executor=make_executor(backend, jobs))
-        try:
-            plans = ev.plan_batch([(groups, acc) for acc in accs])
-        finally:
-            ev.close()
+        ev = CachedEvaluator(g, executor=make_executor(backend))
+        plans = ev.plan_batch([(groups, acc) for acc in accs])
         for got, want in zip(plans, serial_plans):
             assert len(got.subgraphs) == len(want.subgraphs)
             for a, b in zip(got.subgraphs, want.subgraphs):

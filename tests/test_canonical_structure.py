@@ -1,6 +1,6 @@
 """Canonical structure memoization: the content-fingerprint tier of
 :class:`CostKernel`, the disk-backed :class:`StructureCache`, and the
-cross-process shipping of canonical entries.
+shipping of canonical entries from parallel ``compare``'s workers.
 
 The load-bearing property, fuzzed here: *equal canonical keys imply
 field-for-field equal structures* (up to the ``nodes`` stamp) — so a
@@ -18,14 +18,16 @@ from _hypothesis_compat import given, settings, st
 from backend_parity import SYNTH_KINDS, scheme_corpus
 from conftest import small_graph
 
-from repro.api import build_workload
+from repro.api import ExploreSpec, GAOptions, build_workload, compare
 from repro.core import (
     AcceleratorConfig,
     CachedEvaluator,
     CostKernel,
     Graph,
+    HWSpace,
+    Objective,
     compute_structure,
-    make_executor,
+    evaluate_subgraph,
     random_partition,
 )
 from repro.core.cost import SubgraphStructure, canonical_structure_key
@@ -66,7 +68,7 @@ def test_canonical_structures_match_fresh_on_scheme_corpus():
     """Every URI scheme's golden workload, warm canonical memo vs fresh
     compute_structure: field-for-field equality including the nodes stamp."""
     for label, g, _queries in scheme_corpus():
-        kernel = CostKernel(g, canonical=True)
+        kernel = CostKernel(g)
         for fs in _node_sets(g, seed=7):
             _assert_structs_equal(kernel.structure(fs),
                                   compute_structure(g, set(fs)),
@@ -82,7 +84,7 @@ def test_canonical_structures_match_fresh_on_synthetic_sweep():
              for pseed in range(2)]
     for kind, n, gseed, pseed in cases:
         g = build_workload(f"synthetic:{kind}:{n}?seed={gseed}")
-        kernel = CostKernel(g, canonical=True)
+        kernel = CostKernel(g)
         for fs in _node_sets(g, seed=pseed, n_parts=3):
             _assert_structs_equal(kernel.structure(fs),
                                   compute_structure(g, set(fs)),
@@ -95,23 +97,26 @@ def test_canonical_structures_match_fresh_on_synthetic_sweep():
 @settings(max_examples=25, deadline=None)
 def test_property_canonical_structures_match_fresh(kind, n, gseed, pseed):
     g = build_workload(f"synthetic:{kind}:{n}?seed={gseed}")
-    kernel = CostKernel(g, canonical=True)
+    kernel = CostKernel(g)
     for fs in _node_sets(g, seed=pseed, n_parts=3):
         _assert_structs_equal(kernel.structure(fs),
                               compute_structure(g, set(fs)))
 
 
-def test_canonical_costs_equal_canonical_off():
-    """The full cost (structure + finish) is invariant under the memo."""
+def test_memoized_costs_equal_unmemoized():
+    """The full cost (structure + finish) through the memoized kernel equals
+    ``evaluate_subgraph``, which derives every query afresh."""
     g = build_workload("tpu:gemma3-4b:0?tokens=512")
-    on, off = CostKernel(g, canonical=True), CostKernel(g, canonical=False)
+    kernel = CostKernel(g)
     accs = [AcceleratorConfig(glb_bytes=128 * KB, wbuf_bytes=144 * KB),
             AcceleratorConfig(glb_bytes=512 * KB, wbuf_bytes=0, shared=True)]
-    for fs in _node_sets(g, seed=3):
+    sets = _node_sets(g, seed=3)
+    for fs in sets:
         for acc in accs:
-            assert asdict(on.cost(fs, acc)) == asdict(off.cost(fs, acc))
-    assert on.structure_canon_hits > 0  # the workload has repeated blocks
-    assert on.structure_misses < off.structure_misses
+            assert asdict(kernel.cost(fs, acc)) == \
+                asdict(evaluate_subgraph(g, set(fs), acc))
+    assert kernel.structure_canon_hits > 0  # the workload has repeated blocks
+    assert kernel.structure_misses < len(sets)
 
 
 # ---------------------------------------------------------------------------
@@ -120,7 +125,7 @@ def test_canonical_costs_equal_canonical_off():
 
 def test_isomorphic_subgraphs_share_one_entry():
     g = small_graph()  # nodes 1 and 2 are the isomorphic diamond arms
-    kernel = CostKernel(g, canonical=True)
+    kernel = CostKernel(g)
     st1 = kernel.structure(frozenset({1}))
     st2 = kernel.structure(frozenset({2}))
     assert kernel.structure_misses == 1
@@ -174,7 +179,7 @@ def test_sched_error_structures_never_cached_canonically():
     """Error messages embed node indices, so isomorphic failing subgraphs
     must each derive their own (label-correct) error."""
     g, (a, b) = _stride_mismatch_graph()
-    kernel = CostKernel(g, canonical=True)
+    kernel = CostKernel(g)
     st_a = kernel.structure(frozenset(a))
     st_b = kernel.structure(frozenset(b))
     assert st_a.sched_error is not None and st_b.sched_error is not None
@@ -196,7 +201,7 @@ def test_sched_error_structures_never_cached_canonically():
 def test_structcache_roundtrip_and_warm_start(tmp_path):
     g = small_graph()
     cache = StructureCache(tmp_path / "structs")
-    k1 = CostKernel(g, canonical=True, struct_cache=cache)
+    k1 = CostKernel(g, struct_cache=cache)
     sets = [frozenset({1}), frozenset({2}), frozenset({1, 3}),
             frozenset({0, 1, 2, 3})]
     for fs in sets:
@@ -205,7 +210,7 @@ def test_structcache_roundtrip_and_warm_start(tmp_path):
     assert len(cache) == 3
     # a fresh kernel over the same directory derives nothing
     cache2 = StructureCache(tmp_path / "structs")
-    k2 = CostKernel(g, canonical=True, struct_cache=cache2)
+    k2 = CostKernel(g, struct_cache=cache2)
     for fs in sets:
         _assert_structs_equal(k2.structure(fs), compute_structure(g, set(fs)))
     assert k2.structure_misses == 0
@@ -245,54 +250,62 @@ def test_structcache_refuses_sched_error_entries(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# cross-process shipping (process backend, parallel compare)
+# cross-process shipping (parallel compare)
 # ---------------------------------------------------------------------------
 
-def test_process_workers_ship_canonical_structures_back():
-    g = small_graph()
+_STRATEGIES = ["greedy", "dp", "ga"]
+
+
+def _compare_spec():
     acc = AcceleratorConfig(glb_bytes=128 * KB, wbuf_bytes=144 * KB)
-    ev = CachedEvaluator(g, executor=make_executor("process", 2))
-    try:
-        queries = [(fs, acc) for fs in _node_sets(g, seed=5)]
-        ev.evaluate_batch(queries)
-    finally:
-        ev.close()
+    return ExploreSpec(workload="dd", strategy="ga",
+                       objective=Objective(metric="energy", alpha=0.002),
+                       hw=HWSpace(mode="shared", base=acc),
+                       sample_budget=200, seed=0,
+                       options=GAOptions(population=10))
+
+
+def test_parallel_compare_ships_canonical_structures_back():
+    g = small_graph()
+    ev = CachedEvaluator(g)
+    compare(_compare_spec(), _STRATEGIES, graph=g, ev=ev, jobs=2)
     canon = ev.structure_snapshot()
     assert canon, "parent adopted no canonical entries from workers"
-    assert ev.kernel.structure_merged == len(canon)
-    # adopted entries are real structures: payload matches fresh derivation
-    # (the wire format ships them label-free, nodes=(), like the disk tier)
-    by_key = {canonical_structure_key(g, set(fs)): fs for fs, _ in queries}
+    assert ev.kernel.structure_misses == 0  # every entry came from a worker
+    assert ev.kernel.structure_merged == len(canon) > 0
+    # adopted entries are real structures: each equals a fresh derivation
+    # of the node set it was derived for, under the key it was filed by
     for key, st in canon.items():
         assert st.sched_error is None
-        assert st.nodes == ()
-        want = compute_structure(g, set(by_key[key]))
-        assert all(getattr(st, f) == getattr(want, f)
-                   for f in _STRUCT_PAYLOAD)
+        assert canonical_structure_key(g, set(st.nodes)) == key
+        _assert_structs_equal(st, compute_structure(g, set(st.nodes)))
     # the parent now serves those fingerprints without deriving
-    before = ev.kernel.structure_misses
-    for fs, _ in queries:
-        kernel_st = ev.kernel.structure(frozenset(fs))
-        _assert_structs_equal(kernel_st, compute_structure(g, set(fs)))
-    assert ev.kernel.structure_misses == before
+    for st in canon.values():
+        ev.kernel.structure(frozenset(st.nodes))
+    assert ev.kernel.structure_misses == 0
 
 
-def test_process_workers_share_disk_cache(tmp_path):
+def test_parallel_compare_workers_share_disk_cache(tmp_path):
     g = small_graph()
-    acc = AcceleratorConfig(glb_bytes=128 * KB, wbuf_bytes=144 * KB)
-    cache = StructureCache(tmp_path / "structs")
-    ev = CachedEvaluator(g, struct_cache=cache,
-                         executor=make_executor("process", 2))
-    try:
-        ev.evaluate_batch([(fs, acc) for fs in _node_sets(g, seed=5)])
-    finally:
-        ev.close()
-    assert len(cache) > 0  # workers wrote through to the shared directory
+    root = tmp_path / "structs"
+    first = compare(_compare_spec(), _STRATEGIES, graph=g, jobs=2,
+                    struct_cache_dir=str(root))
+    files = {p.name: (p.stat().st_ino, p.stat().st_mtime_ns)
+             for p in root.rglob("*") if p.is_file()}
+    assert files  # workers wrote through to the shared directory
+    # fresh workers read every structure back: they derive (and write)
+    # nothing, and the comparison comes out bitwise the same
+    again = compare(_compare_spec(), _STRATEGIES, graph=g, jobs=2,
+                    struct_cache_dir=str(root))
+    assert {p.name: (p.stat().st_ino, p.stat().st_mtime_ns)
+            for p in root.rglob("*") if p.is_file()} == files
+    assert [r.to_json() for r in again] == [r.to_json() for r in first]
     # a cold serial evaluator warm-starts from the directory alone
-    ev2 = CachedEvaluator(g, struct_cache=StructureCache(tmp_path / "structs"))
-    ev2.subgraph({1}, acc)
-    assert ev2.kernel.structure_misses == 0
-    assert ev2.kernel.structure_disk_hits == 1
+    ev = CachedEvaluator(g, struct_cache=StructureCache(root))
+    ev.subgraph({1}, AcceleratorConfig(glb_bytes=128 * KB,
+                                       wbuf_bytes=144 * KB))
+    assert ev.kernel.structure_misses == 0
+    assert ev.kernel.structure_disk_hits == 1
 
 
 # ---------------------------------------------------------------------------
@@ -310,7 +323,7 @@ def test_canonical_hit_counts_pinned_on_tpu_block():
     measured in docs/benchmarks.md."""
     g = build_workload("tpu:gemma3-4b:0?tokens=512")
     assert g.n == 11
-    kernel = CostKernel(g, canonical=True)
+    kernel = CostKernel(g)
     sets = list(_node_sets(g, seed=7, n_parts=12))
     singles = [frozenset({v}) for v in range(g.n)]
     sets += [fs for fs in singles if fs not in set(sets)]

@@ -8,7 +8,7 @@ import pytest
 from backend_parity import available_backends, backend_params
 from conftest import small_graph
 
-from repro.api import ExploreSpec, GAOptions, SAOptions, run
+from repro.api import ExploreSpec, GAOptions, run
 from repro.core import (
     AcceleratorConfig,
     CachedEvaluator,
@@ -26,7 +26,6 @@ from repro.core import (
 from repro.core.cost import SubgraphStructure
 from repro.core.engine import (
     BACKENDS,
-    ProcessExecutor,
     SerialExecutor,
     VectorExecutor,
     backend_status,
@@ -162,26 +161,14 @@ def test_vector_backend_streaming_and_overflow_paths():
     assert "streamed" in reasons           # the corpus exercised streaming
 
 
-def test_process_executor_matches_serial():
-    g = small_graph()
-    queries = random_queries(g, n_parts=6, seed=2)
-    ex = ProcessExecutor(jobs=2)
-    try:
-        got = ex.evaluate(CostKernel(g), queries)
-    finally:
-        ex.close()
-    want = SerialExecutor().evaluate(CostKernel(g), queries)
-    assert [asdict(c) for c in got] == [asdict(c) for c in want]
-
-
 def test_pool_context_avoids_forking_a_jax_parent():
-    """Once jax is imported, process pools must not use the raw ``fork``
-    start method: jax's at-fork hook warns (and the runtime can deadlock).
-    ``pool_mp_context`` switches to ``forkserver``; with no jax in the
-    process it keeps the platform default."""
+    """Once jax is imported, parallel ``compare``'s process pool must not
+    use the raw ``fork`` start method: jax's at-fork hook warns (and the
+    runtime can deadlock).  ``pool_mp_context`` switches to ``forkserver``;
+    with no jax in the process it keeps the platform default."""
     import sys
 
-    from repro.core.engine import pool_mp_context
+    from repro.api.strategies import pool_mp_context
 
     ctx = pool_mp_context()
     if "jax" in sys.modules:
@@ -192,40 +179,20 @@ def test_pool_context_avoids_forking_a_jax_parent():
         assert ctx.get_start_method() == mp.get_context().get_start_method()
 
 
-def test_process_executor_is_fork_warning_clean_with_jax_loaded():
-    """End-to-end regression for the `os.fork() ... JAX is multithreaded`
-    RuntimeWarning: spin up a real worker pool after importing jax (skips
-    when jax is absent).  Needs > 2*jobs distinct queries so the executor
-    actually spawns workers instead of evaluating inline."""
-    import warnings
-
-    pytest.importorskip("jax")
-    g = small_graph()
-    queries = random_queries(g, n_parts=3, seed=5)
-    assert len(queries) > 2
-    ex = ProcessExecutor(jobs=1)
-    try:
-        with warnings.catch_warnings():
-            warnings.simplefilter("error", RuntimeWarning)
-            got = ex.evaluate(CostKernel(g), queries)
-    finally:
-        ex.close()
-    want = SerialExecutor().evaluate(CostKernel(g), queries)
-    assert [asdict(c) for c in got] == [asdict(c) for c in want]
-
-
 def test_make_executor_resolution():
-    assert isinstance(make_executor(None, 1), SerialExecutor)
-    ex = make_executor(None, 3)
-    assert isinstance(ex, ProcessExecutor) and ex.jobs == 3
-    assert isinstance(make_executor("vector", 1), VectorExecutor)
+    assert BACKENDS == ("serial", "vector", "jax")
+    assert isinstance(make_executor(None), SerialExecutor)
+    assert isinstance(make_executor("serial"), SerialExecutor)
+    assert isinstance(make_executor("vector"), VectorExecutor)
     with pytest.raises(ValueError, match="unknown eval backend"):
-        make_executor("gpu", 1)
+        make_executor("process")
+    with pytest.raises(ValueError, match="unknown eval backend"):
+        make_executor("gpu")
 
 
 def test_make_executor_unknown_backend_lists_valid_backends():
     with pytest.raises(ValueError) as exc:
-        make_executor("gpu", 1)
+        make_executor("gpu")
     for backend in BACKENDS:
         assert backend in str(exc.value)
 
@@ -242,7 +209,7 @@ def test_backend_status_reports_why_unavailable(monkeypatch):
     assert not ok
     assert "No module named 'jax'" in why and "pip install jax" in why
     with pytest.raises(ValueError, match="unavailable"):
-        make_executor("jax", 1)
+        make_executor("jax")
 
 
 def test_jax_backend_resolves_whenever_jax_imports():
@@ -394,8 +361,8 @@ def test_fallback_guard_boundary_noc_product():
     assert needs_scalar_fallback(no_w, replace(acc1, weight_share_cores=edge))
 
 
-@pytest.mark.parametrize("backend,jobs", backend_params())
-def test_fallback_boundary_queries_stay_bitwise_exact(backend, jobs):
+@pytest.mark.parametrize("backend", backend_params())
+def test_fallback_boundary_queries_stay_bitwise_exact(backend):
     """Batched backends answer guard-straddling queries identically to the
     scalar kernel (the fallback partition is an implementation detail)."""
     g = small_graph()
@@ -410,12 +377,8 @@ def test_fallback_boundary_queries_stay_bitwise_exact(backend, jobs):
                for acc in edge_accs]
     queries += [(frozenset({v, v + 1}), acc) for v in range(g.n - 1)
                 for acc in edge_accs]
-    ex = make_executor(backend, jobs)
     kernel = CostKernel(g)
-    try:
-        got = ex.evaluate(CostKernel(g), queries)
-    finally:
-        ex.close()
+    got = make_executor(backend).evaluate(CostKernel(g), queries)
     for (nodes, acc), a in zip(queries, got):
         assert asdict(a) == asdict(kernel.cost(nodes, acc)), (nodes, acc)
 
@@ -424,31 +387,21 @@ def test_fallback_boundary_queries_stay_bitwise_exact(backend, jobs):
 # backend invariance of whole strategy runs
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend,jobs", backend_params())
-def test_parallel_ga_bitwise_identical_to_serial(backend, jobs):
+@pytest.mark.parametrize("backend", backend_params())
+def test_parallel_ga_bitwise_identical_to_serial(backend):
     spec = fixed_spec()
     serial = run(spec, graph=small_graph())
-    other = run(spec, graph=small_graph(), eval_backend=backend,
-                eval_jobs=jobs)
+    other = run(spec, graph=small_graph(), eval_backend=backend)
     assert other.to_json() == serial.to_json()
-
-
-def test_parallel_sa_and_enum_identical_to_serial():
-    for strategy, options in (("sa", SAOptions()), ("enum", None)):
-        spec = fixed_spec(strategy=strategy, options=options)
-        serial = run(spec, graph=small_graph())
-        parallel = run(spec, graph=small_graph(), eval_jobs=2)
-        assert parallel.to_json() == serial.to_json(), strategy
 
 
 def test_count_run_distinct_queries_invariant_across_backends():
     spec = fixed_spec()
     counts = {}
-    for backend, jobs in available_backends():
-        res = run(spec, graph=small_graph(), eval_backend=backend,
-                  eval_jobs=jobs)
+    for backend in available_backends():
+        res = run(spec, graph=small_graph(), eval_backend=backend)
         counts[backend] = res.evaluations
-    assert len(counts) >= 3  # serial + process + vector always resolve
+    assert len(counts) >= 2  # serial + vector always resolve
     assert len(set(counts.values())) == 1, counts
 
 
@@ -459,7 +412,7 @@ def test_evaluations_count_distinct_queries_despite_canonical_hits():
     the accounting."""
     g = small_graph()  # nodes 1 and 2 are isomorphic singletons
     acc = AcceleratorConfig(glb_bytes=128 * KB, wbuf_bytes=144 * KB)
-    ev = CachedEvaluator(g, canonical=True)
+    ev = CachedEvaluator(g)
     with ev.count_run() as touched:
         ev.subgraph({1}, acc)
         ev.subgraph({2}, acc)
@@ -469,28 +422,15 @@ def test_evaluations_count_distinct_queries_despite_canonical_hits():
     assert ev.kernel.structure_canon_hits == 1
 
 
-def test_results_and_evaluations_invariant_under_canonical_toggle(
-        monkeypatch):
-    """REPRO_STRUCT_CANON=0 (the honest-measurement escape hatch) changes
-    nothing observable: bitwise-identical results, same evaluations."""
-    spec = fixed_spec()
-    base = run(spec, graph=small_graph())
-    monkeypatch.setenv("REPRO_STRUCT_CANON", "0")
-    off = run(spec, graph=small_graph())
-    assert off.to_json() == base.to_json()
-    assert off.evaluations == base.evaluations
-
-
 def test_search_result_evaluations_invariant_across_backends():
     """run_ga's raw SearchResult.evaluations (true cache misses), not just
     the distinct-query count run() reports, must not depend on the backend."""
     from repro.core import run_ga
     counts = []
-    for backend, jobs in available_backends():
+    for backend in available_backends():
         g = small_graph()
-        ev = CachedEvaluator(g, executor=make_executor(backend, jobs))
+        ev = CachedEvaluator(g, executor=make_executor(backend))
         res = run_ga(g, Objective(metric="ema", alpha=None), HWSpace(),
                      sample_budget=60, population=10, seed=0, ev=ev)
-        ev.close()
         counts.append(res.evaluations)
     assert len(set(counts)) == 1, counts

@@ -1,11 +1,14 @@
 """Golden regression: seed-fixed GA/greedy results for one workload per URI
 scheme, pinned bitwise and asserted identical across every evaluation
-backend that resolves (``serial`` / ``vector`` / ``process`` / ``jax`` —
+backend that resolves (``serial`` / ``vector`` / ``jax`` —
 the same invariance `tests/test_engine.py` pins for the engine itself; an
 uninstalled jax shows up as a *skip*, not a hole).
 
 The ``ga_full`` case is FULL-budget-shaped: a paper-scale GA population so
-the batched backends see generation-sized miss batches, not toy ones.
+the batched backends see generation-sized miss batches, not toy ones.  The
+``ga_cocco`` cases run the benchmark's own design space (a GA co-exploring
+the shared buffer size under the energy objective) on its two graphs,
+ResNet-50 and the irregular RandWire-A.
 
 Golden artifacts live in ``tests/golden/``; regenerate them after an
 *intentional* cost-model or search change with::
@@ -31,6 +34,7 @@ FILE_URI_CANON = "file:tests/golden/workload_diamond.json"
 
 WORKLOADS = {
     "netlib_resnet50": "netlib:resnet50",
+    "netlib_randwire_a": "netlib:randwire_a",
     "tpu_gemma3-4b_L0": "tpu:gemma3-4b:0?tokens=512",
     "synthetic_layered24": "synthetic:layered:24?seed=7",
     "file_diamond": f"file:{FILE_GRAPH}",
@@ -47,11 +51,22 @@ STRATEGIES = {
     "greedy": ("greedy", GreedyOptions(eval_budget=2_000), 300),
     "ga_full": ("ga", GAOptions(population=64), 1_280),
     "ga_noc": ("ga", GAOptions(population=10), 300),
+    "ga_cocco": ("ga", GAOptions(population=10), 300),
 }
+
+# the Simba-like core and shared-buffer grid of the paper's Sec. 5.3
+# co-exploration: 128 KB to 3 MB in 64 KB steps (47 sizes)
+COCCO_ACC = AcceleratorConfig(
+    glb_bytes=1048576, wbuf_bytes=1179648, shared=False,
+    macs_per_cycle=1024, freq_hz=1e9, dram_bytes_per_sec=16e9,
+    e_dram_pj_per_byte=100.0, e_mac_pj=0.05, n_cores=1,
+    e_noc_pj_per_byte=2.0, weight_share_cores=1)
+COCCO_SHARED_CANDIDATES = tuple(range(131072, 3145728 + 1, 65536))
 
 CASES = [(w, s) for w in WORKLOADS for s in ("ga", "greedy")]
 CASES += [("synthetic_layered24", "ga_full")]
 CASES += [("synthetic_layered24", "ga_noc")]
+CASES += [("netlib_resnet50", "ga_cocco"), ("netlib_randwire_a", "ga_cocco")]
 
 
 def golden_spec(workload_key: str, strategy_key: str) -> ExploreSpec:
@@ -67,6 +82,10 @@ def golden_spec(workload_key: str, strategy_key: str) -> ExploreSpec:
                                    n_cores=2),
             core_candidates=(2, 4),
         )
+    elif strategy_key == "ga_cocco":
+        objective = Objective(metric="energy", alpha=0.002)
+        hw = HWSpace(mode="shared", base=COCCO_ACC,
+                     shared_candidates=COCCO_SHARED_CANDIDATES)
     return ExploreSpec(
         workload=WORKLOADS[workload_key],
         strategy=strategy,
@@ -96,14 +115,14 @@ def golden_path(workload_key: str, strategy: str) -> Path:
     return GOLDEN_DIR / f"{workload_key}.{strategy}.json"
 
 
-@pytest.mark.parametrize("backend,jobs", backend_params(include_serial=True))
+@pytest.mark.parametrize("backend", backend_params(include_serial=True))
 @pytest.mark.parametrize("workload_key,strategy", CASES)
 def test_golden_result_pinned_across_backends(workload_key, strategy,
-                                              backend, jobs):
+                                              backend):
     spec = golden_spec(workload_key, strategy)
     golden = json.loads(golden_path(workload_key, strategy).read_text())
 
-    got = canonical_dict(run(spec, eval_backend=backend, eval_jobs=jobs))
+    got = canonical_dict(run(spec, eval_backend=backend))
     assert got == golden, (
         f"{workload_key}/{strategy} [{backend}] drifted from tests/golden/ "
         f"— if the cost model or search changed intentionally, regenerate "
