@@ -29,6 +29,7 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field, replace as dataclass_replace
 from typing import (
     Any,
+    ClassVar,
     Dict,
     Iterator,
     List,
@@ -174,6 +175,17 @@ class SubgraphCost:
     noc_bytes: int = 0
     feasible: bool = True
     reason: str = ""
+    # (energy point, energy_pj term) of the last PlanCost.energy_pj read,
+    # one tuple so that threads sharing a cached cost never pair a point with
+    # another point's term; the class default matches no point.  Not a
+    # field, so equality, repr and asdict ignore it; __getstate__ keeps it
+    # out of pickles and copies
+    _energy_memo: ClassVar[Tuple[Optional[Tuple], float]] = (None, 0.0)
+
+    def __getstate__(self) -> Dict[str, Any]:
+        state = dict(self.__dict__)
+        state.pop("_energy_memo", None)
+        return state
 
     @property
     def ema_total(self) -> int:
@@ -239,7 +251,25 @@ class PlanCost:
 
     @property
     def energy_pj(self) -> float:
-        return sum(s.energy_pj(self.acc) for s in self.subgraphs)
+        # each term is memoized on its SubgraphCost at the plan's energy
+        # point; sum() in plan order as ever (float sum() is compensated,
+        # so a running += would round differently)
+        acc = self.acc
+        # every field of acc that SubgraphCost.energy_pj reads
+        point = (acc.shared, acc.glb_bytes, acc.wbuf_bytes,
+                 acc.e_dram_pj_per_byte, acc.e_noc_pj_per_byte, acc.e_mac_pj)
+        terms: List[float] = []
+        misses = 0
+        for s in self.subgraphs:
+            memo = s._energy_memo
+            if memo[0] != point:
+                memo = s._energy_memo = (point, s.energy_pj(acc))
+                misses += 1
+            terms.append(memo[1])
+        rec = obs.current()
+        rec.add("plan.energy_terms", len(terms))
+        rec.add("plan.energy_term_misses", misses)
+        return sum(terms)
 
     @property
     def latency_cycles(self) -> float:
