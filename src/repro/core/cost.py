@@ -702,6 +702,7 @@ class CostKernel:
         self.structure_misses = 0
         self.structure_merged = 0     # canonical entries adopted from peers
         self.structure_time_s = 0.0   # wall time inside compute_structure
+        self.structure_derived_nodes = 0  # layers those derivations covered
 
     def structure(self, nodes: frozenset) -> SubgraphStructure:
         st = self._structures.get(nodes)
@@ -725,6 +726,7 @@ class CostKernel:
         st = compute_structure(self.g, set(nodes), out_tile=self.out_tile)
         self.structure_time_s += time.perf_counter() - t0
         self.structure_misses += 1
+        self.structure_derived_nodes += len(nodes)
         self._structures[nodes] = st
         if st.sched_error is None:
             self._canon[key] = st
@@ -940,6 +942,7 @@ class CachedEvaluator:
             "structure_misses": k.structure_misses,
             "structure_merged": k.structure_merged,
             "structure_derive_s": k.structure_time_s,
+            "structure_derived_nodes": k.structure_derived_nodes,
         }
         if k.struct_cache is not None:
             out["structure_disk_writes"] = k.struct_cache.writes

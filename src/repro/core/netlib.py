@@ -5,13 +5,16 @@ multi-branch: ResNet50 / ResNet152 [20], GoogleNet [59], Transformer [64], GPT [
 irregular:    RandWire-A/B [68] (RandWire-WS small / regular regime of
               arXiv:1904.01569 Table 2; Watts-Strogatz instances from
               assumed seeds, networkx),
-              NasNet-A [75]
+              NasNet-A [75] (the mobile NASNet-A 4 @ 1056 of
+              arXiv:1707.07012 Table 2, after TF-slim's nets/nasnet;
+              no auxiliary head)
 
 Modelling conventions (paper §5.1.1): FC layers are 1x1 convolutions; pooling
 and element-wise layers are depth-wise convolutions without weights; scalar
 ops (activations) are hidden in the PE pipeline.  Activations and weights are
 INT8 (1 byte/element).  The sliding axis is the feature-map height (rows);
-``line_bytes = W_out * C_out``.  'same' padding: H_out = ceil(H/s).
+``line_bytes = W_out * C_out``.  'same' padding: H_out = ceil(H/s);
+'valid' padding (NASNet's conv0): H_out = (H - F) // s + 1.
 """
 
 from __future__ import annotations
@@ -36,9 +39,16 @@ class NetBuilder:
 
     def conv(self, src: int, cout: int, f: int = 1, s: int = 1,
              name: str = "conv", depthwise: bool = False,
-             weightless: bool = False) -> int:
+             weightless: bool = False, padding: str = "same",
+             shift: int = 0) -> int:
+        """A ``f``x``f`` stride-``s`` layer.  ``padding`` 'valid' gives
+        ``(H - f) // s + 1`` rows; ``shift`` rows widen the edge's window
+        for a layer that reads its input shifted down by that many rows."""
         h, w, c = self.shapes[src]
-        ho, wo = math.ceil(h / s), math.ceil(w / s)
+        if padding == "valid":
+            ho, wo = (h - f) // s + 1, (w - f) // s + 1
+        else:
+            ho, wo = math.ceil(h / s), math.ceil(w / s)
         if depthwise:
             cout = c
             wbytes = 0 if weightless else f * f * c
@@ -47,7 +57,7 @@ class NetBuilder:
             wbytes = 0 if weightless else f * f * c * cout
             macs = ho * wo * cout * f * f * c
         idx = self.g.add_node(name, ho, wo * cout, wbytes, macs)
-        self.g.add_edge(src, idx, F=min(f, h), s=s)
+        self.g.add_edge(src, idx, F=min(f, h) + shift, s=s)
         self.shapes[idx] = (ho, wo, cout)
         return idx
 
@@ -348,75 +358,125 @@ def randwire(variant: str = "A") -> Graph:
     return b.done(x)
 
 
-def _nasnet_sep(b: NetBuilder, x: int, c: int, f: int, s: int, tag: str) -> int:
-    dw = b.conv(x, 0, f, s, name=f"{tag}.dw", depthwise=True)
-    return b.conv(dw, c, 1, 1, name=f"{tag}.pw")
+# NASNet-A (4 @ 1056), the mobile ImageNet model of Zoph et al., "Learning
+# Transferable Architectures for Scalable Image Recognition",
+# arXiv:1707.07012, Table 2, with the layer equations of TF-slim's
+# ``nets/nasnet`` (``mobile_imagenet_config``, ``_imagenet_stem``,
+# ``NasNetABaseCell``).  A cell is (operations, hiddenstate_indices,
+# used_hiddenstates) as TF-slim publishes it: block ``i`` adds
+# ``operations[2i]`` of state ``hiddenstate_indices[2i]`` to
+# ``operations[2i+1]`` of state ``hiddenstate_indices[2i+1]``; states 0 and 1
+# are the cell's two inputs, state ``2 + i`` is block ``i``'s sum, and the
+# cell concatenates the unused states.
+NASNET_NORMAL = (
+    ("separable_5x5_2", "separable_3x3_2", "separable_5x5_2",
+     "separable_3x3_2", "avg_pool_3x3", "none", "avg_pool_3x3",
+     "avg_pool_3x3", "separable_3x3_2", "none"),
+    (0, 1, 1, 1, 0, 1, 1, 1, 0, 0),
+    (1, 0, 0, 0, 0, 0, 0),
+)
+NASNET_REDUCTION = (
+    ("separable_5x5_2", "separable_7x7_2", "max_pool_3x3",
+     "separable_7x7_2", "avg_pool_3x3", "separable_5x5_2", "none",
+     "avg_pool_3x3", "separable_3x3_2", "max_pool_3x3"),
+    (0, 1, 0, 1, 0, 1, 3, 2, 2, 0),
+    (1, 1, 1, 0, 0, 0, 0),
+)
+NASNET_CELLS, NASNET_FILTERS, NASNET_REDUCTIONS = 12, 44, (4, 8)
 
 
-def _nasnet_adjust(b: NetBuilder, h: int, hm1: int, c: int,
-                   tag: str) -> Tuple[int, int]:
-    """Cell-entry squeeze: project both states to c channels / matching H."""
-    hh = b.shapes[h][0]
-    h = b.conv(h, c, 1, 1, name=f"{tag}.sq_h")
-    s = max(1, b.shapes[hm1][0] // hh)
-    hm1 = b.conv(hm1, c, 1, s, name=f"{tag}.sq_hm1")
-    return h, hm1
+def _nasnet_factorized(b: NetBuilder, x: int, c: int, tag: str) -> int:
+    """TF-slim ``factorized_reduction`` at stride 2: two strided 1x1 convs of
+    c/2 channels, the second on the input shifted by one row and column,
+    concatenated."""
+    p1 = b.conv(x, c // 2, 1, 2, name=f"{tag}.path1")
+    p2 = b.conv(x, c - c // 2, 1, 2, name=f"{tag}.path2", shift=1)
+    return b.concat([p1, p2], name=f"{tag}.path")
 
 
-def _nasnet_normal(b: NetBuilder, h: int, hm1: int, c: int, tag: str) -> int:
-    """NasNet-A normal cell (5 blocks, Zoph et al. Fig. 4)."""
-    h, hm1 = _nasnet_adjust(b, h, hm1, c, tag)
-    b1 = b.eltwise([_nasnet_sep(b, h, c, 3, 1, f"{tag}.b1l"), h],
-                   name=f"{tag}.b1")
-    b2 = b.eltwise([_nasnet_sep(b, hm1, c, 3, 1, f"{tag}.b2l"),
-                    _nasnet_sep(b, h, c, 5, 1, f"{tag}.b2r")],
-                   name=f"{tag}.b2")
-    b3 = b.eltwise([b.pool(h, 3, 1, name=f"{tag}.b3l"), hm1],
-                   name=f"{tag}.b3")
-    b4 = b.eltwise([b.pool(hm1, 3, 1, name=f"{tag}.b4l"),
-                    b.pool(hm1, 3, 1, name=f"{tag}.b4r")],
-                   name=f"{tag}.b4")
-    b5 = b.eltwise([_nasnet_sep(b, hm1, c, 5, 1, f"{tag}.b5l"),
-                    _nasnet_sep(b, hm1, c, 3, 1, f"{tag}.b5r")],
-                   name=f"{tag}.b5")
-    return b.concat([b1, b2, b3, b4, b5], name=f"{tag}.cat")
+def _nasnet_op(b: NetBuilder, x: int, op: str, c: int, s: int,
+               tag: str) -> int:
+    """TF-slim ``_apply_conv_operation``: one operand of a block."""
+    cin = b.shape(x)[2]
+    if op.startswith("separable"):
+        # separable_kxk_n: n ReLU-SepConv-BN layers, the stride on the first
+        k, n = int(op.split("_")[1].split("x")[0]), int(op.split("_")[2])
+        for i in range(1, n + 1):
+            x = b.conv(x, 0, k, s if i == 1 else 1, name=f"{tag}.sep{i}.dw",
+                       depthwise=True)
+            x = b.conv(x, c, 1, 1, name=f"{tag}.sep{i}.pw")
+        return x
+    if op == "none":
+        if s > 1 or cin != c:
+            x = b.conv(x, c, 1, s, name=f"{tag}.1x1")
+        return x
+    x = b.pool(x, 3, s, name=f"{tag}.pool")     # avg_pool_3x3 / max_pool_3x3
+    if cin != c:
+        x = b.conv(x, c, 1, 1, name=f"{tag}.1x1")
+    return x
 
 
-def _nasnet_reduction(b: NetBuilder, h: int, hm1: int, c: int, tag: str) -> int:
-    """NasNet-A reduction cell (stride-2 blocks)."""
-    h, hm1 = _nasnet_adjust(b, h, hm1, c, tag)
-    b1 = b.eltwise([_nasnet_sep(b, hm1, c, 7, 2, f"{tag}.b1l"),
-                    _nasnet_sep(b, h, c, 5, 2, f"{tag}.b1r")],
-                   name=f"{tag}.b1")
-    b2 = b.eltwise([b.pool(h, 3, 2, name=f"{tag}.b2l"),
-                    _nasnet_sep(b, hm1, c, 7, 2, f"{tag}.b2r")],
-                   name=f"{tag}.b2")
-    b3 = b.eltwise([b.pool(h, 3, 2, name=f"{tag}.b3l"),
-                    _nasnet_sep(b, hm1, c, 5, 2, f"{tag}.b3r")],
-                   name=f"{tag}.b3")
-    b4 = b.eltwise([b.pool(b1, 3, 1, name=f"{tag}.b4l"), b2],
-                   name=f"{tag}.b4")
-    b5 = b.eltwise([_nasnet_sep(b, b1, c, 3, 1, f"{tag}.b5l"), b3],
-                   name=f"{tag}.b5")
-    return b.concat([b2, b4, b5], name=f"{tag}.cat")
+def _nasnet_cell(b: NetBuilder, h: int, hm1: Optional[int], c: int, s: int,
+                 cell: Tuple[Tuple, Tuple, Tuple], tag: str) -> int:
+    """One NASNet-A cell of ``c`` filters at stride ``s`` on the last output
+    ``h`` and the one before it ``hm1`` (TF-slim ``NasNetABaseCell``)."""
+    ops, inputs, used = cell
+    # _reduce_prev_layer: bring h_{i-1} to h's width and c channels
+    if hm1 is None:
+        prev = h
+    elif b.shape(hm1)[1] != b.shape(h)[1]:
+        prev = _nasnet_factorized(b, hm1, c, f"{tag}.prev")
+    elif b.shape(hm1)[2] != c:
+        prev = b.conv(hm1, c, 1, 1, name=f"{tag}.prev_1x1")
+    else:
+        prev = hm1
+    states = [b.conv(h, c, 1, 1, name=f"{tag}.1x1"), prev]
+    for i in range(5):
+        operands = [
+            _nasnet_op(b, states[inputs[j]], ops[j], c,
+                       s if inputs[j] < 2 else 1, f"{tag}.comb{i}.{side}")
+            for j, side in ((2 * i, "left"), (2 * i + 1, "right"))]
+        states.append(b.eltwise(operands, name=f"{tag}.comb{i}"))
+    # _combine_unused_states: reduce unused states unlike the last, concat
+    _, fw, fc = b.shape(states[-1])
+    out = []
+    for i, state in enumerate(states):
+        if used[i]:
+            continue
+        _, w, ch = b.shape(state)
+        if w != fw:
+            state = _nasnet_factorized(b, state, fc, f"{tag}.reduce{i}")
+        elif ch != fc:
+            state = b.conv(state, fc, 1, 1, name=f"{tag}.reduce{i}")
+        out.append(state)
+    return b.concat(out, name=f"{tag}.out")
 
 
-def nasnet(cells_per_stack: int = 4, c0: int = 44) -> Graph:
-    """NasNet-A (mobile-ish: N=4, 44 filters)."""
+def nasnet() -> Graph:
+    """NASNet-A (4 @ 1056): conv0 3x3/2 VALID to 32 at 111; two stem
+    reduction cells of 11 and 22 filters (56, 28); 12 normal cells of 44, 88
+    and 176 filters at 28, 14 and 7, a reduction cell of twice the filters
+    before cells 4 and 8; each cell reads the last two outputs.  Then global
+    pooling and a 1000-way fc.  The auxiliary head trains only and is left
+    out."""
     b = NetBuilder("nasnet", 224, 224, 3)
-    x = b.conv(b.input, 32, 3, 2, name="stem")
-    hm1, h = x, x
-    c = c0
-    for stack in range(3):
-        if stack > 0:
+    x = b.conv(b.input, 32, 3, 2, name="conv0", padding="valid")
+    outs: List[Optional[int]] = [None, x]
+    for i in range(2):    # filter scaling 1/4, then 1/2
+        x = _nasnet_cell(b, x, outs[-2], NASNET_FILTERS >> (2 - i), 2,
+                         NASNET_REDUCTION, f"cell_stem_{i}")
+        outs.append(x)
+    c = NASNET_FILTERS
+    for i in range(NASNET_CELLS):
+        if i in NASNET_REDUCTIONS:
             c *= 2
-            r = _nasnet_reduction(b, h, hm1, c, f"r{stack}")
-            hm1, h = h, r
-        for i in range(cells_per_stack):
-            n = _nasnet_normal(b, h, hm1, c, f"s{stack}c{i}")
-            hm1, h = h, n
-    x = b.global_pool(h)
-    x = b.fc(x, 1000, "fc")
+            x = _nasnet_cell(b, x, outs[-2], c, 2, NASNET_REDUCTION,
+                             f"reduction_cell_{NASNET_REDUCTIONS.index(i)}")
+            outs.append(x)
+        x = _nasnet_cell(b, x, outs[-2], c, 1, NASNET_NORMAL, f"cell_{i}")
+        outs.append(x)
+    x = b.global_pool(x, "final_layer.pool")
+    x = b.fc(x, 1000, "final_layer.fc")
     return b.done(x)
 
 

@@ -69,6 +69,48 @@ def test_randwire_a_is_the_published_small_regime():
         [(e["src"], e["dst"], e["F"], e["s"], e["kind"]) for e in doc["edges"]]
 
 
+def test_nasnet_is_the_published_mobile_network():
+    """``nasnet`` is node for node and edge for edge the graph of the
+    benchmark's NASNet-A configuration (arXiv:1707.07012 Table 2, 4 @ 1056,
+    after TF-slim's ``nets/nasnet``)."""
+    path = (Path(__file__).resolve().parents[1] / "bench" / "configs" /
+            "nasnet.json")
+    doc = json.loads(path.read_text())["graph"]
+    g = build("nasnet")
+    assert (g.n, len(g.edges)) == (511, 666)
+    assert [(v.out_len, v.line_bytes, v.weight_bytes, v.macs,
+             bool(v.is_output)) for v in g.nodes] == \
+        [(n["out_len"], n["line_bytes"], n["weight_bytes"], n["macs"],
+          n["is_output"]) for n in doc["nodes"]]
+    assert [(e.src, e.dst, e.F, e.s, e.kind) for e in g.edges] == \
+        [(e["src"], e["dst"], e["F"], e["s"], e["kind"]) for e in doc["edges"]]
+
+
+def test_nasnet_has_the_published_shape():
+    """Table 2's NASNet-A (4 @ 1056): 5.3 M parameters, 564 M mult-adds;
+    four normal cells at each of 28x28x264, 14x14x528 and 7x7x1056 behind a
+    VALID stem conv of 111 rows; a normal cell concatenates six states, a
+    reduction cell four."""
+    g = build("nasnet")
+    by_name = {v.name: v for v in g.nodes}
+    assert by_name["conv0"].out_len == 111
+    stages = Counter((v.out_len, v.line_bytes) for v in g.nodes
+                     if v.name.startswith("cell_") and v.name.endswith(".out")
+                     and not v.name.startswith("cell_stem"))
+    assert stages == {(28, 28 * 264): 4, (14, 14 * 528): 4,
+                      (7, 7 * 1056): 4}
+    assert abs(g.total_weight_bytes() / 5.3e6 - 1) < 0.02
+    weighted_macs = sum(v.macs for v in g.nodes if v.weight_bytes)
+    assert abs(weighted_macs / 564e6 - 1) < 0.02
+    fan_in = {v.name: len(g.in_edges(v.idx)) for v in g.nodes
+              if v.name.endswith(".out")}
+    assert {n: k for n, k in fan_in.items() if "reduction" in n
+            or "stem" in n} == {"cell_stem_0.out": 4, "cell_stem_1.out": 4,
+                                "reduction_cell_0.out": 4,
+                                "reduction_cell_1.out": 4}
+    assert [fan_in[f"cell_{i}.out"] for i in range(12)] == [6] * 12
+
+
 def test_randwire_b_has_the_regular_regime_stages():
     """Table 2, regular regime (C = 109): conv1 to C/2 at 112, then random
     stages of N/2, N, N and N nodes with C, 2C, 4C and 8C channels at 56,
