@@ -6,7 +6,10 @@ directory.  Generations are read from the program's ``ga.generation``
 spans; a span opened after the window closes aborts the search in flight,
 so the run never waits for it.  ``samples_per_s`` is the samples of every
 generation that completed in the window over the seconds from the window's
-start to the end of the last of them.
+start to the end of the last of them.  ``plan_cost`` is the objective of
+the plan the run's first search returned: every run completes one, and a
+faster program completes more searches, so only the first is the same work
+on both sides of a change.
 """
 
 from __future__ import annotations
@@ -135,8 +138,17 @@ class Driver:
         return len(self.errors)
 
     def e2e(self) -> dict:
+        """``samples_per_s`` from the window's clock, and ``plan_cost``:
+        the objective of the plan the first search returned (seeded
+        ``derive_seed(seed, 0)``), as the program reported it.  Each is
+        left out where the window gave it nothing to read."""
+        out = {}
         rate = samples_per_s(self.t0, self.t_end, self.rec.generations)
-        return {} if rate is None else {"samples_per_s": rate}
+        if rate is not None:
+            out["samples_per_s"] = rate
+        if self.results:
+            out["plan_cost"] = self.results[0].cost
+        return out
 
     def checks(self) -> List[common.Check]:
         plans = common.PlanChecker(self.config)
